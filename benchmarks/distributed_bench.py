@@ -17,6 +17,7 @@ import numpy as np
 
 from repro.data.synthetic import powerlaw_graph
 from repro.core import oracle
+from repro.launch.mesh import emulated_devices_env
 
 
 def simulate_collective_volume(n_nodes=800, m_per_node=6, seed=0):
@@ -87,9 +88,7 @@ for delta in (False, True):
         distributed_decompose(spec, mesh, np.asarray(edges), delta=delta)
     print(f"delta={{delta}} {{(time.perf_counter()-t0)/3*1e6:.0f}}")
 """
-    env = dict(os.environ,
-               XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}",
-               PYTHONPATH=os.path.join(root, "src"))
+    env = emulated_devices_env(devices, PYTHONPATH=os.path.join(root, "src"))
     out = subprocess.run([sys.executable, "-c", code], env=env, cwd=root,
                          capture_output=True, text=True, timeout=600)
     if out.returncode != 0:

@@ -38,6 +38,8 @@ import subprocess
 import sys
 import tempfile
 
+from repro.launch.mesh import emulated_devices_env
+
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 #: graph operating points: (n_nodes, m_per_node, max_degree) — degree capped
@@ -114,8 +116,7 @@ def run_point(devices: int, partition: str, graph: tuple, *,
                           partition=partition, n=n, m_per=m_per, cap=cap,
                           seed=SEED, decompose=decompose,
                           oracle_path=oracle_path)
-    env = dict(os.environ,
-               XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}")
+    env = emulated_devices_env(devices)
     out = subprocess.run([sys.executable, "-c", code], env=env, cwd=ROOT,
                          capture_output=True, text=True, timeout=timeout)
     if out.returncode != 0:

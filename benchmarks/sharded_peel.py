@@ -30,6 +30,8 @@ import os
 import subprocess
 import sys
 
+from repro.launch.mesh import emulated_devices_env
+
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 DEVICE_COUNTS = (1, 2, 4, 8)
 
@@ -125,8 +127,7 @@ print("RESULT " + json.dumps(out))
 def run_point(devices: int, n: int, m_per: int, repeats: int) -> dict:
     code = _WORKER.format(src=os.path.join(ROOT, "src"), devices=devices,
                           n=n, m_per=m_per, repeats=repeats)
-    env = dict(os.environ,
-               XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}")
+    env = emulated_devices_env(devices)
     out = subprocess.run([sys.executable, "-c", code], env=env, cwd=ROOT,
                          capture_output=True, text=True, timeout=900)
     if out.returncode != 0:

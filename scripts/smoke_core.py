@@ -25,6 +25,7 @@ sys.path.insert(0, "src")
 
 from repro.core import (GraphSpec, from_edge_list, decompose, DynamicGraph,
                         oracle)
+from repro.launch.mesh import emulated_devices_env
 
 
 def rand_graph(rng, n, p):
@@ -222,8 +223,7 @@ orc.apply(ups)
 assert g1.phi_dict() == g2.phi_dict() == orc.phi
 print("ok")
 """
-    env = dict(os.environ,
-               XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}")
+    env = emulated_devices_env(devices)
     out = subprocess.run([sys.executable, "-c", code], env=env,
                          capture_output=True, text=True, timeout=600)
     assert out.returncode == 0, out.stdout + "\n" + out.stderr
@@ -268,8 +268,7 @@ for sh in bm.addressable_shards:
     assert sh.data.nbytes == spec.bitmap_bytes_per_device
 print("ok %d edges %d waves" % (len(edges), int(ps2.waves)))
 """
-    env = dict(os.environ,
-               XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}")
+    env = emulated_devices_env(devices)
     out = subprocess.run([sys.executable, "-c", code], env=env,
                          capture_output=True, text=True, timeout=900)
     assert out.returncode == 0, out.stdout + "\n" + out.stderr

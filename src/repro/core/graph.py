@@ -20,6 +20,7 @@ vectorized binary search (``searchsorted``) instead of pointer chasing.
 from __future__ import annotations
 
 import dataclasses
+from functools import partial
 from typing import NamedTuple
 
 import jax
@@ -440,17 +441,84 @@ def phi_of(st: GraphState, e_cap: int, ids: jax.Array) -> jax.Array:
     return jnp.where(ids < e_cap, st.phi[jnp.minimum(ids, e_cap - 1)], 0)
 
 
+#: Neighbor-list widths of the support pass's degree classes (plus
+#: ``d_max``): an edge whose endpoints have degrees <= (ka, kc) is counted
+#: by comparing the first ka and kc entries of their sorted rows.
+SUPPORT_WIDTHS = (16, 128, 1024)
+
+#: Elements of one ``[rows, ka, kc]`` compare block of the support pass.
+SUPPORT_BLOCK_ELEMS = 1 << 22
+
+
 def support(spec: GraphSpec, st: GraphState, u: jax.Array, v: jax.Array,
             alive: jax.Array | None = None) -> jax.Array:
     """Global support sup(e, G) for query edges; optionally restricted to an
-    ``alive`` mask over edge slots (used by peeling)."""
-    id1, id2, valid = triangle_partners(spec, st, u, v)
+    ``alive`` mask over edge slots (used by peeling).
+
+    Each edge's common neighbors are found by comparing its endpoints'
+    neighbor rows all against all, with no per-element search: rows hold
+    their neighbors first and sentinels last, so an endpoint of degree
+    <= k needs only its first k entries.  Edges are grouped by the degree
+    classes (``SUPPORT_WIDTHS``) of their two endpoints and each group runs
+    in blocks of ``SUPPORT_BLOCK_ELEMS`` compares, so the pass reads and
+    compares about ``sum(k_a * k_c)`` entries instead of ``E * d_max``
+    searches — under a heavy-tailed degree law almost every edge is in the
+    smallest class.  Row gathers and compares are what a TPU does fast;
+    per-element gathers (a binary search) are not."""
+    n, e_cap = spec.n_nodes, spec.e_cap
+    al = None
     if alive is not None:
         al = jnp.concatenate([alive, jnp.zeros((1,), bool)])  # slot e_cap → False
-        ok1 = al[jnp.minimum(id1, spec.e_cap)]
-        ok2 = al[jnp.minimum(id2, spec.e_cap)]
-        valid = valid & ok1 & ok2
-    return jnp.sum(valid, axis=1).astype(jnp.int32)
+    widths = tuple(w for w in SUPPORT_WIDTHS if w < spec.d_max) + (spec.d_max,)
+    n_cls = len(widths)
+
+    deg = jnp.sum(st.nbr < n, axis=1, dtype=jnp.int32)
+    du, dv = deg[u], deg[v]
+    lo = jnp.where(du <= dv, u, v)        # lower-degree endpoint first
+    hi = jnp.where(du <= dv, v, u)
+    cls_of = partial(jnp.searchsorted, jnp.asarray(widths))
+    cls = cls_of(jnp.minimum(du, dv)) * n_cls + cls_of(jnp.maximum(du, dv))
+    order = jnp.argsort(cls).astype(jnp.int32)
+    bounds = jnp.searchsorted(cls[order], jnp.arange(n_cls * n_cls + 1))
+    b = u.shape[0]
+    most = max(1, SUPPORT_BLOCK_ELEMS // (widths[0] * widths[0]))
+    lo_s = jnp.pad(lo[order], (0, most))   # block reads never clamp
+    hi_s = jnp.pad(hi[order], (0, most))
+    pos_s = jnp.pad(order, (0, most), constant_values=b)
+
+    def count(a, c, ka, kc):
+        wa, ea = st.nbr[a, :ka], st.eid[a, :ka]          # [R, ka]
+        wc, ec = st.nbr[c, :kc], st.eid[c, :kc]          # [R, kc]
+        match = ((wa[:, :, None] == wc[:, None, :])
+                 & (wa < n)[:, :, None])                 # [R, ka, kc]
+        found = jnp.any(match, axis=2)
+        # rows are sets: at most one match per entry, so the sum is its id
+        id_cw = jnp.sum(jnp.where(match, ec[:, None, :], 0), axis=2)
+        if al is not None:
+            found = (found & al[jnp.minimum(ea, e_cap)]
+                     & al[jnp.minimum(id_cw, e_cap)])
+        return jnp.sum(found, axis=1, dtype=jnp.int32)
+
+    out = jnp.zeros((b,), jnp.int32)
+    for ia, ka in enumerate(widths):
+        for ic in range(ia, n_cls):
+            kc = widths[ic]
+            rows = max(1, SUPPORT_BLOCK_ELEMS // (ka * kc))
+            start = bounds[ia * n_cls + ic]
+            end = bounds[ia * n_cls + ic + 1]
+
+            # a block's rows past ``end`` belong to later classes, which
+            # run later and overwrite them (or to padding, dropped)
+            def block(i, out, start=start, rows=rows, ka=ka, kc=kc):
+                off = start + i * rows
+                a = jax.lax.dynamic_slice_in_dim(lo_s, off, rows)
+                c = jax.lax.dynamic_slice_in_dim(hi_s, off, rows)
+                pos = jax.lax.dynamic_slice_in_dim(pos_s, off, rows)
+                return out.at[pos].set(count(a, c, ka, kc), mode="drop")
+
+            out = jax.lax.fori_loop(0, (end - start + rows - 1) // rows,
+                                    block, out)
+    return out
 
 
 def support_all(spec: GraphSpec, st: GraphState, alive: jax.Array) -> jax.Array:
@@ -577,7 +645,6 @@ def build_bitmap_partitioned(spec: GraphSpec, st: GraphState,
     slab and drops out-of-slab bits — value-equal to ``build_bitmap``, laid
     out ``P(None, shard_axis)`` with O(N·W/S) resident per device."""
     from jax.sharding import PartitionSpec as P
-    from ..compat import shard_map
 
     ax, wb = spec.shard_axis, spec.word_block
 
@@ -586,8 +653,9 @@ def build_bitmap_partitioned(spec: GraphSpec, st: GraphState,
         return partial_bitmap(spec, edges, valid,
                               word_offset=off, word_count=wb)
 
-    return shard_map(local_fn, mesh=mesh, in_specs=(P(), P()),
-                     out_specs=P(None, ax), check=False)(st.edges, alive)
+    return jax.shard_map(local_fn, mesh=mesh, in_specs=(P(), P()),
+                         out_specs=P(None, ax),
+                         check_vma=False)(st.edges, alive)
 
 
 def update_bitmap_partitioned(spec: GraphSpec, bm: jax.Array, u: jax.Array,
@@ -597,7 +665,6 @@ def update_bitmap_partitioned(spec: GraphSpec, bm: jax.Array, u: jax.Array,
     applies only the bits landing in its slab, so the per-slab updates
     compose to exactly the ``update_bitmap`` result with zero exchange."""
     from jax.sharding import PartitionSpec as P
-    from ..compat import shard_map
 
     ax, wb = spec.shard_axis, spec.word_block
 
@@ -606,9 +673,10 @@ def update_bitmap_partitioned(spec: GraphSpec, bm: jax.Array, u: jax.Array,
         return update_bitmap(spec, bm, u, v, valid, set_bits=set_bits,
                              word_offset=off, word_count=wb)
 
-    return shard_map(local_fn, mesh=mesh,
-                     in_specs=(P(None, ax), P(), P(), P()),
-                     out_specs=P(None, ax), check=False)(bm, u, v, valid)
+    return jax.shard_map(local_fn, mesh=mesh,
+                         in_specs=(P(None, ax), P(), P(), P()),
+                         out_specs=P(None, ax),
+                         check_vma=False)(bm, u, v, valid)
 
 
 def support_all_bitmap(spec: GraphSpec, st: GraphState, alive: jax.Array,

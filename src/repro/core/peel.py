@@ -379,15 +379,14 @@ def _decision_probe(mesh, ax: str):
     fn = _PROBE_CACHE.get(key)
     if fn is None:
         from jax.sharding import PartitionSpec as P
-        from ..compat import shard_map
 
         def local_fn(x):
             """Per-shard body: one decision pmin over replicated lanes."""
             s, f, d, w = _decision(x[0], x[1], x[2] > 0, x[3] > 0, ax)
             return s + f + d.astype(jnp.int32) + w.astype(jnp.int32)
 
-        fn = jax.jit(shard_map(local_fn, mesh=mesh, in_specs=(P(),),
-                               out_specs=P(), check=False))
+        fn = jax.jit(jax.shard_map(local_fn, mesh=mesh, in_specs=(P(),),
+                                   out_specs=P(), check_vma=False))
         _PROBE_CACHE[key] = fn
     return fn
 
@@ -676,7 +675,6 @@ def _sharded_delta_bitmap(spec: GraphSpec, edges, active, phi0, peel_mask,
     disjoint-bit partials are exact bitwise-ors/clears), the fused
     ``peel_wave`` kernel running unchanged on each shard's row block."""
     from jax.sharding import PartitionSpec as P
-    from ..compat import shard_map
     from ..kernels import ops as kernel_ops  # kernels never import core
 
     e_cap, n, ax = spec.e_cap, spec.n_nodes, spec.shard_axis
@@ -731,10 +729,10 @@ def _sharded_delta_bitmap(spec: GraphSpec, edges, active, phi0, peel_mask,
                 jax.lax.psum(out.kills, ax), jax.lax.psum(out.deltas, ax),
                 jax.lax.psum(jnp.sum(peelm, dtype=jnp.int32), ax))
 
-    mapped = shard_map(local_fn, mesh=mesh,
-                       in_specs=(P(ax, None), P(ax), P(ax), P(ax), P()),
-                       out_specs=(P(ax), P(), P(), P(), P()),
-                       check=False)
+    mapped = jax.shard_map(local_fn, mesh=mesh,
+                           in_specs=(P(ax, None), P(ax), P(ax), P(ax), P()),
+                           out_specs=(P(ax), P(), P(), P(), P()),
+                           check_vma=False)
     return mapped(edges, active, phi0, peel_mask, bitmap)
 
 
@@ -773,7 +771,6 @@ def _partitioned_bitmap_peel(spec: GraphSpec, edges, active, phi0, peel_mask,
     lets the scale tier run ≥1M-edge graphs per device.
     """
     from jax.sharding import PartitionSpec as P
-    from ..compat import shard_map
     from ..kernels import ops as kernel_ops  # kernels never import core
 
     e_cap, n, ax = spec.e_cap, spec.n_nodes, spec.shard_axis
@@ -876,10 +873,10 @@ def _partitioned_bitmap_peel(spec: GraphSpec, edges, active, phi0, peel_mask,
         phi_blk = jax.lax.dynamic_slice_in_dim(phi, idx * blk, blk)
         return phi_blk, waves, kills, deltas, frontier
 
-    mapped = shard_map(local_fn, mesh=mesh,
-                       in_specs=(P(), P(), P(), P(), P(None, ax)),
-                       out_specs=(P(ax), P(), P(), P(), P()),
-                       check=False)
+    mapped = jax.shard_map(local_fn, mesh=mesh,
+                           in_specs=(P(), P(), P(), P(), P(None, ax)),
+                           out_specs=(P(ax), P(), P(), P(), P()),
+                           check_vma=False)
     return mapped(edges, active, phi0, peel_mask, bitmap)
 
 
@@ -891,7 +888,6 @@ def _sharded_recompute(spec: GraphSpec, edges, active, phi0, peel_mask,
     — psum'd partial bitmaps (``bitmap``) or replicated adjacency rows
     against the all-gathered qualifying mask (``sorted``)."""
     from jax.sharding import PartitionSpec as P
-    from ..compat import shard_map
     from ..kernels import ops as kernel_ops  # kernels never import core
 
     e_cap, n, ax = spec.e_cap, spec.n_nodes, spec.shard_axis
@@ -946,8 +942,8 @@ def _sharded_recompute(spec: GraphSpec, edges, active, phi0, peel_mask,
         return (jnp.where(active, phi, 0), waves, jax.lax.psum(kills, ax),
                 jax.lax.psum(jnp.sum(peelm, dtype=jnp.int32), ax))
 
-    mapped = shard_map(local_fn, mesh=mesh,
-                       in_specs=(P(ax, None), P(ax), P(ax), P(ax), P(), P()),
-                       out_specs=(P(ax), P(), P(), P()),
-                       check=False)
+    mapped = jax.shard_map(local_fn, mesh=mesh,
+                           in_specs=(P(ax, None), P(ax), P(ax), P(ax), P(), P()),
+                           out_specs=(P(ax), P(), P(), P()),
+                           check_vma=False)
     return mapped(edges, active, phi0, peel_mask, nbr, eid)

@@ -8,9 +8,18 @@ Inputs are the *pre-gathered* rows (``rows_a = bitmap[u]``, ``rows_b =
 bitmap[v]``): the gather stays in XLA where it can fuse with the producing
 scatter, and the kernel owns the hot elementwise-reduce loop.
 
-Tiling: grid = (E/EB, W/WB); the output block for edge-tile i is revisited
-across the W dimension (sequential minor grid axis on TPU), accumulating
-partial popcount sums in VMEM.
+Tiling: grid = (cdiv(E, EB), cdiv(W, WB)); the output block for edge-tile i
+is revisited across the W dimension (sequential minor grid axis on TPU),
+accumulating partial popcount sums in VMEM.
+
+* ``EDGE_BLOCK = 1024``: XLA tiles a 1-D int32 array as ``T(1024)``, and
+  Mosaic refuses a 1-D operand whose block tiles differently — so every
+  1-D block (outputs, the alive mask) is 1024 rows, or the whole array
+  when it is shorter.
+* Neither axis is padded: ``jnp.pad`` of the ``[E, W]`` rows would copy
+  gigabytes at real sizes.  Partial edge tiles past ``E`` compute rows
+  that are never stored; partial word tiles past ``W`` are masked by
+  column index, so out-of-range words never reach a popcount.
 """
 from __future__ import annotations
 
@@ -19,29 +28,50 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-EDGE_BLOCK = 512
+EDGE_BLOCK = 1024
 WORD_BLOCK = 256
 
 
-def _kernel(a_ref, b_ref, o_ref):
+def tiling(e: int, w: int) -> tuple[int, int]:
+    """(edge block, word block) for ``[e, w]`` rows: full-dimension blocks
+    when an axis is shorter than its block, else the fixed tiles."""
+    return (EDGE_BLOCK if e > EDGE_BLOCK else e,
+            WORD_BLOCK if w > WORD_BLOCK else w)
+
+
+def tile_support(a_ref, b_ref, w: int):
+    """Popcount of ``a & b`` over one ``(eb, wb)`` tile, summed per row —
+    columns at or past ``w`` (a partial last word tile) count zero."""
+    inter = jax.lax.population_count(a_ref[...] & b_ref[...]).astype(jnp.int32)
+    wb = inter.shape[1]
+    if w % wb:
+        col = (pl.program_id(1) * wb
+               + jax.lax.broadcasted_iota(jnp.int32, inter.shape, 1))
+        inter = jnp.where(col < w, inter, 0)
+    return jnp.sum(inter, axis=1)
+
+
+#: edge tiles are independent; the word axis accumulates into one block
+COMPILER_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "arbitrary"))
+
+
+def _kernel(a_ref, b_ref, o_ref, *, w):
     j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    inter = jax.lax.population_count(a_ref[...] & b_ref[...])
-    o_ref[...] += jnp.sum(inter.astype(jnp.int32), axis=1)
+    o_ref[...] += tile_support(a_ref, b_ref, w)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "edge_block",
-                                             "word_block", "row_count",
+@functools.partial(jax.jit, static_argnames=("interpret", "row_count",
                                              "word_count"))
 def bitmap_support_kernel(rows_a: jax.Array, rows_b: jax.Array, *,
                           interpret: bool = False,
-                          edge_block: int = EDGE_BLOCK,
-                          word_block: int = WORD_BLOCK,
                           row_offset=0, row_count: int | None = None,
                           word_offset=0,
                           word_count: int | None = None) -> jax.Array:
@@ -70,23 +100,16 @@ def bitmap_support_kernel(rows_a: jax.Array, rows_b: jax.Array, *,
         rows_b = jax.lax.dynamic_slice_in_dim(rows_b, word_offset, word_count,
                                               axis=1)
     e, w = rows_a.shape
-    eb = min(edge_block, max(8, e))
-    wb = min(word_block, max(1, w))
-    e_pad = -e % eb
-    w_pad = -w % wb
-    a = jnp.pad(rows_a, ((0, e_pad), (0, w_pad)))
-    b = jnp.pad(rows_b, ((0, e_pad), (0, w_pad)))
-    ep, wp = a.shape
-
-    out = pl.pallas_call(
-        _kernel,
-        grid=(ep // eb, wp // wb),
+    eb, wb = tiling(e, w)
+    return pl.pallas_call(
+        functools.partial(_kernel, w=w),
+        grid=(pl.cdiv(e, eb), pl.cdiv(w, wb)),
         in_specs=[
             pl.BlockSpec((eb, wb), lambda i, j: (i, j)),
             pl.BlockSpec((eb, wb), lambda i, j: (i, j)),
         ],
         out_specs=pl.BlockSpec((eb,), lambda i, j: (i,)),
-        out_shape=jax.ShapeDtypeStruct((ep,), jnp.int32),
+        out_shape=jax.ShapeDtypeStruct((e,), jnp.int32),
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
-    )(a, b)
-    return out[:e]
+    )(rows_a, rows_b)

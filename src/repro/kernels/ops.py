@@ -1,9 +1,12 @@
 """jit'd public wrappers around the Pallas kernels.
 
-On a TPU backend the real kernels run; everywhere else (this CPU container,
-unit tests) they execute in ``interpret=True`` mode so the *same kernel body*
+On a TPU backend the real kernels run; everywhere else (CPU hosts, unit
+tests) they execute in ``interpret=True`` mode so the *same kernel body*
 is validated numerically.  ``use_kernels(False)`` drops to the pure-jnp
 references entirely (useful for A/B benchmarking and as an escape hatch).
+
+Every dispatch decision asks ``on_tpu()``, so a test that compiles for a
+described TPU from a CPU host patches that one function.
 """
 from __future__ import annotations
 
@@ -25,8 +28,14 @@ def use_kernels(flag: bool) -> None:
     _USE_KERNELS = flag
 
 
+def on_tpu() -> bool:
+    """Whether kernels compile for a TPU (else they interpret or fall back
+    to the references) — the one backend probe of this module."""
+    return jax.default_backend() == "tpu"
+
+
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    return not on_tpu()
 
 
 def _slab(row_offset, row_count, *arrays):
@@ -85,11 +94,11 @@ def bitmap_support_gathered(bitmap, eu, ev, chunk=None):
     everywhere else the fused XLA reference serves (interpret-mode
     emulation in the hot loop costs ~40x).
     """
-    on_tpu = _USE_KERNELS and jax.default_backend() == "tpu"
+    use_kernel = _USE_KERNELS and on_tpu()
 
     def one(a, b):
         rows_a, rows_b = bitmap[a], bitmap[b]
-        if on_tpu:
+        if use_kernel:
             return bitmap_support_kernel(rows_a, rows_b)
         return ref.bitmap_support_ref(rows_a, rows_b)
 
@@ -111,7 +120,7 @@ def peel_wave(rows_a, rows_b, alive, k, row_offset=0, row_count=None):
     # per wave), where interpret-mode emulation costs ~40x over the fused
     # XLA reference.  The kernel body itself is still validated in
     # interpret mode by tests/test_peel_engine.py.
-    if _USE_KERNELS and jax.default_backend() == "tpu":
+    if _USE_KERNELS and on_tpu():
         return peel_wave_kernel(rows_a, rows_b, alive, k,
                                 row_offset=row_offset, row_count=row_count)
     rows_a, rows_b, alive = _slab(row_offset, row_count, rows_a, rows_b, alive)

@@ -9,11 +9,13 @@ kernel extends it: one VMEM pass over the ``[E, W]`` uint32 rows computes
 
 so the peel loop's level-k frontier comes out of the same accumulation that
 produced the counts — no second trip through the edge axis.  ``k`` rides in
-as a (1, 1) scalar block so one compiled kernel serves every peel level.
+as a one-element SMEM operand so one compiled kernel serves every peel
+level.
 
-Tiling matches ``bitmap_support``: grid = (E/EB, W/WB) with the word axis
-minor (sequentially revisited on TPU); the output blocks for edge-tile i
-accumulate partials across j and the threshold fires on the last word tile.
+Tiling is ``bitmap_support``'s (same blocks, same unpadded partial tiles):
+grid = (cdiv(E, EB), cdiv(W, WB)) with the word axis minor (sequentially
+revisited on TPU); the output blocks for edge-tile i accumulate partials
+across j and the threshold fires on the last word tile.
 """
 from __future__ import annotations
 
@@ -22,35 +24,31 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-EDGE_BLOCK = 512
-WORD_BLOCK = 256
+from .bitmap_support import COMPILER_PARAMS, tile_support, tiling
 
 
-def _kernel(k_ref, a_ref, b_ref, alive_ref, sup_ref, kill_ref):
+def _kernel(k_ref, a_ref, b_ref, alive_ref, sup_ref, kill_ref, *, w):
     j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
         sup_ref[...] = jnp.zeros_like(sup_ref)
 
-    inter = jax.lax.population_count(a_ref[...] & b_ref[...])
-    sup_ref[...] += jnp.sum(inter.astype(jnp.int32), axis=1)
+    sup_ref[...] += tile_support(a_ref, b_ref, w)
 
     @pl.when(j == pl.num_programs(1) - 1)
     def _emit():
         alive = alive_ref[...] != 0
         sup = jnp.where(alive, sup_ref[...], 0)
         sup_ref[...] = sup
-        kill_ref[...] = (alive & (sup < k_ref[0, 0] - 2)).astype(jnp.int32)
+        kill_ref[...] = (alive & (sup < k_ref[0] - 2)).astype(jnp.int32)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "edge_block",
-                                             "word_block", "row_count"))
+@functools.partial(jax.jit, static_argnames=("interpret", "row_count"))
 def peel_wave_kernel(rows_a: jax.Array, rows_b: jax.Array, alive: jax.Array,
                      k: jax.Array, *, interpret: bool = False,
-                     edge_block: int = EDGE_BLOCK,
-                     word_block: int = WORD_BLOCK,
                      row_offset=0, row_count: int | None = None):
     """Fused (support, kill-frontier) for uint32 bitmap rows [E, W].
 
@@ -73,21 +71,14 @@ def peel_wave_kernel(rows_a: jax.Array, rows_b: jax.Array, alive: jax.Array,
         rows_b = jax.lax.dynamic_slice_in_dim(rows_b, row_offset, row_count)
         alive = jax.lax.dynamic_slice_in_dim(alive, row_offset, row_count)
     e, w = rows_a.shape
-    eb = min(edge_block, max(8, e))
-    wb = min(word_block, max(1, w))
-    e_pad = -e % eb
-    w_pad = -w % wb
-    a = jnp.pad(rows_a, ((0, e_pad), (0, w_pad)))
-    b = jnp.pad(rows_b, ((0, e_pad), (0, w_pad)))
-    al = jnp.pad(alive.astype(jnp.int32), (0, e_pad))
-    k_arr = jnp.asarray(k, jnp.int32).reshape(1, 1)
-    ep, wp = a.shape
+    eb, wb = tiling(e, w)
+    k_arr = jnp.asarray(k, jnp.int32).reshape(1)
 
     sup, kill = pl.pallas_call(
-        _kernel,
-        grid=(ep // eb, wp // wb),
+        functools.partial(_kernel, w=w),
+        grid=(pl.cdiv(e, eb), pl.cdiv(w, wb)),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((eb, wb), lambda i, j: (i, j)),
             pl.BlockSpec((eb, wb), lambda i, j: (i, j)),
             pl.BlockSpec((eb,), lambda i, j: (i,)),
@@ -97,9 +88,10 @@ def peel_wave_kernel(rows_a: jax.Array, rows_b: jax.Array, alive: jax.Array,
             pl.BlockSpec((eb,), lambda i, j: (i,)),
         ),
         out_shape=(
-            jax.ShapeDtypeStruct((ep,), jnp.int32),
-            jax.ShapeDtypeStruct((ep,), jnp.int32),
+            jax.ShapeDtypeStruct((e,), jnp.int32),
+            jax.ShapeDtypeStruct((e,), jnp.int32),
         ),
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
-    )(k_arr, a, b, al)
-    return sup[:e], kill[:e] != 0
+    )(k_arr, rows_a, rows_b, alive.astype(jnp.int32))
+    return sup, kill != 0

@@ -5,6 +5,8 @@ multi-pod = (2, 16, 16) over ("pod", "data", "model"), pod axis = pure DP.
 """
 from __future__ import annotations
 
+import os
+
 import numpy as np
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -41,6 +43,16 @@ def make_shard_mesh(n_shards: int | None = None, axis: str = "shard") -> Mesh:
     if len(devices) < n:
         raise RuntimeError(f"need {n} devices, found {len(devices)}")
     return Mesh(np.asarray(devices[:n]), (axis,))
+
+
+def emulated_devices_env(n_devices: int, **extra: str) -> dict:
+    """Environment for a child process that emulates ``n_devices`` host
+    devices.  ``JAX_PLATFORMS=cpu`` pins the child to the CPU: an
+    accelerator serves one process, so a child that inherited its parent's
+    backend would contend with the parent for the chip."""
+    return dict(os.environ, JAX_PLATFORMS="cpu",
+                XLA_FLAGS=f"--xla_force_host_platform_device_count={n_devices}",
+                **extra)
 
 
 def dp_axes(mesh: Mesh):

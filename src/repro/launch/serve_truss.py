@@ -64,6 +64,7 @@ from ..service import (COMMUNITY, CONSISTENCY_LEVELS, MAX_K, MEMBERS,
                        REPRESENTATIVES, Overloaded, QueryRequest,
                        TrussService, TrussStore)
 from ..service.api import Unavailable
+from .compile_cache import configure_compile_cache
 
 
 def _pipeline_kw(args) -> dict:
@@ -394,6 +395,7 @@ def main(argv=None):
                          "the drive loop — lets probes observe the final "
                          "serving state before exit")
     args = ap.parse_args(argv)
+    configure_compile_cache()
 
     ks = tuple(int(k) for k in args.ks.split(","))
     rng = np.random.default_rng(args.seed)

@@ -16,7 +16,6 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from ..compat import shard_map
 
 Params = dict[str, Any]
 COMPUTE_DTYPE = jnp.bfloat16
@@ -341,10 +340,10 @@ def _expert_block_dispatch(fn, dest, updates, gates, w, n_experts: int):
 
     w_specs = {k: (P_(None, "model", None) if k == "w_down"
                    else P_(None, None, "model")) for k in w}
-    return shard_map(
+    return jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P_(dp, None), P_(dp, None, None), P_(dp, None), w_specs),
-        out_specs=P_(dp, None, None), check=False,
+        out_specs=P_(dp, None, None), check_vma=False,
     )(dest, updates, gates, w)
 
 

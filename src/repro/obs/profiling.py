@@ -11,12 +11,21 @@ Two guards keep this safe in a serving loop: ``jax.profiler`` traces don't
 nest, so a region entered inside an active region records nothing extra
 (reentrance guard); and ``max_traces`` caps how many traces a long run
 writes (profiling every generation of a million-write ingest would fill
-the disk before it filled a timeline).
+the disk before it filled a timeline).  A trace that fails to start does
+not stop the region it wraps, but it is counted
+(``truss_profiler_start_failures_total``) so a measurement run can refuse
+a trace that silently never happened.
 """
 from __future__ import annotations
 
 import os
 from contextlib import contextmanager
+
+from . import metrics, trace
+
+_START_FAILED = metrics.counter(
+    "truss_profiler_start_failures_total",
+    "jax.profiler traces that failed to start (the region ran untraced)")
 
 _DIR: str | None = None
 _MAX = 8
@@ -55,8 +64,11 @@ def profile_region(name: str):
     _ACTIVE = True
     try:
         jax.profiler.start_trace(path)
-    except Exception:
+    except Exception as exc:
         _ACTIVE = False  # profiler unavailable on this backend/build
+        _START_FAILED.inc()
+        trace.instant("profiler.start_failed", region=name,
+                      err=repr(exc)[:120])
         yield
         return
     try:

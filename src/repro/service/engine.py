@@ -763,12 +763,8 @@ class TrussService:
         inf = self._inflight
         if inf is None:
             return False
-        if not wait:
-            try:
-                if not bool(inf.hi.is_ready()):
-                    return False
-            except AttributeError:  # very old jax: no readiness probe —
-                pass                # fall through and block (serial-ish)
+        if not wait and not inf.hi.is_ready():
+            return False
         # int(hi) blocks until the whole fused executable (phi included —
         # one jit call, one executable) has landed, then the deferred index
         # invalidation runs before any query can read labels

@@ -84,3 +84,38 @@ def test_graph_update_stream_resumable():
     c2 = [s2.next() for _ in range(3)]
     for a, b in zip(c1, c2):
         np.testing.assert_array_equal(a, b)
+
+
+def _rank_sampled_chunk(present: set, n: int, chunk: int, seed, step):
+    """Reference sampler: every delete sorts the live edge set afresh."""
+    rng = np.random.default_rng((seed, step))
+    out = []
+    for _ in range(chunk):
+        if rng.random() < 0.5 or not present:
+            while True:
+                a, b = rng.integers(0, n, size=2)
+                a, b = int(min(a, b)), int(max(a, b))
+                if a != b and (a, b) not in present:
+                    break
+            present.add((a, b))
+            out.append((streams.OP_INSERT, a, b))
+        else:
+            e = sorted(present)[rng.integers(len(present))]
+            present.discard(e)
+            out.append((streams.OP_DELETE, *e))
+    return out
+
+
+def test_graph_update_stream_sorted_view_matches_per_delete_sort():
+    """The stream keeps one sorted view of its edge set in step with every
+    insert and delete; it must draw what sorting at each delete draws,
+    across chunks and across a state_dict round trip."""
+    edges = synthetic.powerlaw_graph(50, 3, seed=8)
+    present = {(int(a), int(b)) for a, b in edges}
+    s = streams.GraphUpdateStream(edges, 50, chunk=25, seed=9)
+    for step in range(4):
+        if step == 2:
+            s = streams.GraphUpdateStream(edges, 50, chunk=25, seed=9)\
+                .load_state_dict(s.state_dict())
+        got = [tuple(map(int, r)) for r in s.next()]
+        assert got == _rank_sampled_chunk(present, 50, 25, 9, step)

@@ -10,7 +10,8 @@ from repro.kernels.flash_attention import flash_attention_kernel
 from repro.kernels.segment_matmul import segment_matmul_kernel
 
 
-@pytest.mark.parametrize("e,w", [(1, 1), (7, 3), (64, 32), (130, 37), (513, 129)])
+@pytest.mark.parametrize("e,w", [(1, 1), (7, 3), (64, 32), (130, 37), (513, 129),
+                                 (1030, 1), (2500, 300)])
 def test_bitmap_support_shapes(e, w):
     rng = np.random.default_rng(e * 1000 + w)
     a = jnp.asarray(rng.integers(0, 2**32, size=(e, w), dtype=np.uint32))

@@ -39,7 +39,8 @@ _phi_dict = oracle.phi_snapshot
 # peel_wave kernel (interpret mode)
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("e,w", [(1, 1), (7, 3), (64, 32), (130, 37), (513, 129)])
+@pytest.mark.parametrize("e,w", [(1, 1), (7, 3), (64, 32), (130, 37), (513, 129),
+                                 (1030, 1), (2500, 300)])
 def test_peel_wave_kernel_shapes(e, w):
     rng = np.random.default_rng(e * 1000 + w)
     a = jnp.asarray(rng.integers(0, 2**32, size=(e, w), dtype=np.uint32))
@@ -78,6 +79,47 @@ def test_delta_peel_matches_oracle(method):
         phi_r = decompose(SPEC, st, method, "recompute")
         assert _phi_dict(st, phi_d) == ref_phi, (method, seed)
         np.testing.assert_array_equal(np.asarray(phi_d), np.asarray(phi_r))
+
+
+@pytest.mark.parametrize("widths,block", [((16, 128, 1024), 1 << 22),
+                                          ((8, 32), 192)])
+def test_support_pass_matches_partner_search(monkeypatch, widths, block):
+    """``support`` counts common neighbors by comparing the first k entries
+    of both endpoints' rows, grouped by degree class and run in blocks; it
+    must equal the binary-search partner enumeration of
+    ``triangle_partners`` for every class pair and block size, and the
+    sorted peels on top of it must stay exact."""
+    from repro.core import graph, support_all
+    from repro.core.graph import triangle_partners
+
+    monkeypatch.setattr(graph, "SUPPORT_WIDTHS", widths)
+    monkeypatch.setattr(graph, "SUPPORT_BLOCK_ELEMS", block)
+    n, d_max = 90, 96
+    rng = np.random.default_rng(5)
+    # two hubs joined to everyone (degree ~89) over a sparse remainder of
+    # degrees ~2-16: edges fall in every class pair of (8, 32, 96)
+    edges = sorted({(min(a, b), max(a, b)) for a in (0, 1)
+                    for b in range(n) if a != b}
+                   | {(i, j) for i in range(2, n) for j in range(i + 1, n)
+                      if rng.random() < 0.08})
+    spec = GraphSpec(n_nodes=n, d_max=d_max, e_cap=len(edges) + 10)
+    st = from_edge_list(spec, np.asarray(edges))
+    u = jnp.minimum(st.edges[:, 0], n - 1)
+    v = jnp.minimum(st.edges[:, 1], n - 1)
+    id1, id2, valid = triangle_partners(spec, st, u, v)
+    for alive in (st.active, st.active & (jnp.arange(spec.e_cap) % 3 != 0)):
+        al = np.append(np.asarray(alive), False)
+        ok = (np.asarray(valid) & al[np.minimum(np.asarray(id1), spec.e_cap)]
+              & al[np.minimum(np.asarray(id2), spec.e_cap)])
+        exp = np.where(np.asarray(alive), ok.sum(axis=1), 0)
+        np.testing.assert_array_equal(
+            np.asarray(support_all(spec, st, alive)), exp)
+    # a spec of its own, so the jitted peels trace under these constants
+    spec = GraphSpec(n_nodes=n, d_max=d_max, e_cap=len(edges) + 11 + block)
+    st = from_edge_list(spec, np.asarray(edges))
+    for engine in ("recompute", "delta"):
+        phi = decompose(spec, st, "sorted", engine)
+        assert _phi_dict(st, phi) == _scratch_phi(set(edges), n), engine
 
 
 def test_delta_peel_chunked_waves_and_stats():
