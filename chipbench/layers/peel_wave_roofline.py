@@ -1,0 +1,19 @@
+"""Pallas kernel (``kernels/peel_wave.py``): share of the HBM roofline.
+
+Each ``peel_wave_kernel`` call's bytes are counted from that call's own operand
+and result shapes in the trace (``tracing.call_bytes``), so a call handed
+fewer rows counts fewer bytes.  The call moves O(1) bytes per operation,
+so bytes bound it: the least time is bytes over the chip's HBM bandwidth,
+and the share is that over the kernel's summed device time."""
+KERNEL = "peel_wave_kernel"
+
+
+def read(ctx):
+    if ctx.trace is None:
+        return None
+    calls = ctx.trace.kernel_calls(KERNEL)
+    secs = sum(s for s, _ in calls)
+    if not calls or secs <= 0:
+        return None
+    least = sum(b for _, b in calls) / ctx.peaks["hbm_bytes_per_s"]
+    return 100.0 * least / secs
