@@ -307,6 +307,68 @@ class DynamicGraph:
         ups = [(int(op), int(a), int(b)) for op, a, b in updates]
         if not ups:
             return
+        with obs_trace.span("graph.net", updates=len(ups)):
+            cur, dels, inss = self._net(ups)
+            n_net = len(dels) + len(inss)
+            if strategy == "auto":
+                strategy = ("fused" if n_net >= fused_threshold
+                            else "progressive")
+            if n_net and strategy == "fused":
+                padded = self._fused_args(cur, dels, inss)
+        if n_net == 0:
+            return None
+        if strategy == "progressive":
+            for a, b in dels:
+                self.delete(a, b)
+            for a, b in inss:
+                self.insert(a, b)
+            return None
+        if strategy != "fused":
+            raise ValueError(f"unknown strategy {strategy!r}")
+        # warm the cache from the PRE-update state, then fold the structural
+        # changes in: batch_maintain's delta-peel wants the POST-update
+        # adjacency bitmap
+        if self.support_method == "bitmap":
+            with obs_trace.span("graph.bitmap_apply", dels=len(dels),
+                                ins=len(inss)):
+                self._bitmap_cache()
+                self._bitmap_apply(dels, inss)
+        try:
+            # span covers the host-side apply window: the fused re-peel is
+            # dispatched here and lands later (gen.wait below, or the
+            # service's gen.land span with defer_sync)
+            with obs_trace.span("graph.apply_batch", dels=len(dels),
+                                ins=len(inss), defer=defer_sync):
+                self.state, _lo, hi, stats = batch.batch_maintain(
+                    self.spec, self.state, *padded,
+                    method=self.support_method, engine=engine,
+                    bitmap=self._bitmap, mesh=self.mesh)
+        except BaseException:
+            # the cache already describes the post-update edge set but
+            # state/_present still the pre-update one — drop it rather than
+            # let later bitmap-method peels read a diverged cache
+            self._bitmap = None
+            raise
+        self.last_peel_stats = stats
+        self._present = cur
+        if defer_sync:
+            # async-dispatch mode: the re-peel is in flight; hand the device
+            # scalar back so the caller can overlap host work and invalidate
+            # once the result lands
+            return hi
+        # int(hi) blocks until the whole fused executable has landed.
+        # Updated edges join/leave every level below the range too (they can
+        # merge or split components there), so invalidate [2, hi + 1]; the
+        # mixed-batch fallback returns hi = +inf, i.e. invalidate everything.
+        with obs_trace.span("gen.wait"):
+            hi = int(hi)
+        self.index.invalidate(2, max(hi, 1))
+        return None
+
+    def _net(self, ups):
+        """Net a batch against the present edge set: ``(cur, dels,
+        inss)`` — the edge set after the batch and the sorted edges it
+        removes and adds (an edge inserted then deleted cancels)."""
         present0 = self._present
         cur = set(present0)
         for op, a, b in ups:
@@ -321,21 +383,12 @@ class DynamicGraph:
                 if key not in cur:
                     raise ValueError(f"delete of absent edge {key}")
                 cur.discard(key)
-        dels = sorted(present0 - cur)
-        inss = sorted(cur - present0)
-        n_net = len(dels) + len(inss)
-        if n_net == 0:
-            return None
-        if strategy == "auto":
-            strategy = "fused" if n_net >= fused_threshold else "progressive"
-        if strategy == "progressive":
-            for a, b in dels:
-                self.delete(a, b)
-            for a, b in inss:
-                self.insert(a, b)
-            return None
-        if strategy != "fused":
-            raise ValueError(f"unknown strategy {strategy!r}")
+        return cur, sorted(present0 - cur), sorted(cur - present0)
+
+    def _fused_args(self, cur, dels, inss):
+        """Grow capacities for the edge set ``cur`` if it needs more, and
+        pad ``dels``/``inss`` to one power-of-two length: the six
+        ``(a, b, valid)`` device arrays ``batch_maintain`` takes."""
         final = np.asarray(sorted(cur), np.int64).reshape(-1, 2)
         deg = (np.bincount(final.reshape(-1), minlength=self.spec.n_nodes)
                if len(final) else np.zeros(self.spec.n_nodes, np.int64))
@@ -354,42 +407,7 @@ class DynamicGraph:
             return (jnp.asarray(arr[:, 0]), jnp.asarray(arr[:, 1]),
                     jnp.asarray(msk))
 
-        da, db, dm = pad(dels)
-        ia, ib, im = pad(inss)
-        # warm the cache from the PRE-update state, then fold the structural
-        # changes in: batch_maintain's delta-peel wants the POST-update
-        # adjacency bitmap
-        if self.support_method == "bitmap":
-            self._bitmap_cache()
-            self._bitmap_apply(dels, inss)
-        try:
-            # span covers the host-side apply window: with defer_sync the
-            # fused re-peel is dispatched here and lands later (the
-            # service's gen.land span covers the wait)
-            with obs_trace.span("graph.apply_batch", dels=len(dels),
-                                ins=len(inss), defer=defer_sync):
-                self.state, _lo, hi, stats = batch.batch_maintain(
-                    self.spec, self.state, da, db, dm, ia, ib, im,
-                    method=self.support_method, engine=engine,
-                    bitmap=self._bitmap, mesh=self.mesh)
-        except BaseException:
-            # the cache already describes the post-update edge set but
-            # state/_present still the pre-update one — drop it rather than
-            # let later bitmap-method peels read a diverged cache
-            self._bitmap = None
-            raise
-        self.last_peel_stats = stats
-        self._present = cur
-        if defer_sync:
-            # async-dispatch mode: the re-peel is in flight; hand the device
-            # scalar back so the caller can overlap host work and invalidate
-            # once the result lands
-            return hi
-        # Updated edges join/leave every level below the range too (they can
-        # merge or split components there), so invalidate [2, hi + 1]; the
-        # mixed-batch fallback returns hi = +inf, i.e. invalidate everything.
-        self.index.invalidate(2, max(int(hi), 1))
-        return None
+        return (*pad(dels), *pad(inss))
 
     def batch_update_then_decompose(self, updates):
         """batchUpdate baseline: apply structural updates, re-decompose."""

@@ -84,50 +84,17 @@ the memory curve is ``benchmarks/million_edge.py``.
 """
 from __future__ import annotations
 
-import time
 from functools import partial
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 
-from ..obs import metrics as obs_metrics, trace as obs_trace
 from .graph import (GraphSpec, GraphState, build_bitmap, partial_bitmap,
                     support, support_all, support_all_bitmap,
                     triangle_partners, update_bitmap)
 
 _INF = jnp.int32(2**30)
-
-# -- wave-level profiling (measurement mode; see set_wave_profile) ----------
-_WAVE_S = obs_metrics.histogram(
-    "truss_peel_wave_seconds",
-    "wall time of one host-stepped peel wave (wave-profile mode only)",
-    buckets=(1e-5, 2.5e-5, 5e-5, 1e-4, 2.5e-4, 5e-4, 1e-3, 2.5e-3, 5e-3,
-             1e-2, 2.5e-2, 5e-2, 0.1, 0.25))
-_WAVE_COLL = obs_metrics.histogram(
-    "truss_peel_wave_collective_share",
-    "estimated fraction of one wave spent in the per-wave decision "
-    "all-reduce (wave-profile mode under a mesh)",
-    buckets=(0.01, 0.02, 0.05, 0.1, 0.2, 0.3, 0.4, 0.5, 0.6, 0.7, 0.8, 0.9))
-
-_WAVE_PROFILE = False
-
-
-def set_wave_profile(on: bool = True):
-    """Toggle wave-level profiling process-wide (``serve_truss
-    --wave-profile``).  While on, ``peel`` routes through a host-stepped
-    recompute loop that times **each wave individually** — one device sync
-    per wave, so this is a measurement mode, not a serving mode.  phi is
-    unchanged (every engine computes the same decomposition); ``PeelStats``
-    reflects the recompute discipline."""
-    global _WAVE_PROFILE
-    _WAVE_PROFILE = bool(on)
-
-
-def wave_profile_enabled() -> bool:
-    """Whether ``peel`` currently runs the host-stepped profiled loop."""
-    return _WAVE_PROFILE
-
 
 # ---------------------------------------------------------------------------
 # wave primitives — shared with maintenance.py (Algorithms 1/2 frontiers)
@@ -189,6 +156,10 @@ class PeelStats(NamedTuple):
     frontier: peelable edges entering the peel (|peel_mask ∩ active| — the
               affected-set size on the fused batch path, E on a full
               decompose)
+    closure_iters: the fused batch's affected-set closure iterations
+    closure_unfiltered: 1 when that closure ran without the range filter
+              (the mixed-batch fallback); ``batch.batch_maintain`` fills
+              both in, a peel alone leaves them 0
 
     Every engine (delta/recompute, single-device/sharded) fills every
     field identically, so the sharded bitwise-equality tests compare these
@@ -200,6 +171,8 @@ class PeelStats(NamedTuple):
     kills: jax.Array
     deltas: jax.Array
     frontier: jax.Array = 0
+    closure_iters: jax.Array = 0
+    closure_unfiltered: jax.Array = 0
 
 
 EMPTY_STATS = PeelStats(0, 0, 0, 0)
@@ -209,7 +182,9 @@ def stats_dict(ps: PeelStats) -> dict:
     """Host-int dict of a ``PeelStats`` (``int()`` blocks until device
     arrays land — call only after the peel's results are needed anyway)."""
     return {"waves": int(ps.waves), "kills": int(ps.kills),
-            "deltas": int(ps.deltas), "frontier": int(ps.frontier)}
+            "deltas": int(ps.deltas), "frontier": int(ps.frontier),
+            "closure_iters": int(ps.closure_iters),
+            "closure_unfiltered": int(ps.closure_unfiltered)}
 
 
 class _Carry(NamedTuple):
@@ -242,11 +217,6 @@ def peel(spec: GraphSpec, st: GraphState, peel_mask: jax.Array,
     """
     if engine == "auto":
         engine = "delta" if method == "bitmap" else "recompute"
-    if _WAVE_PROFILE and not isinstance(peel_mask, jax.core.Tracer):
-        # host-stepped profiling needs concrete arrays: a peel reached
-        # through an outer jit trace (the fused batch engine) stays on the
-        # fused engines, so flipping the flag mid-serve is always safe
-        return _profiled_peel(spec, st, peel_mask, method=method, mesh=mesh)
     if mesh is not None:
         return sharded_peel(spec, st, peel_mask, bitmap=bitmap, method=method,
                             engine=engine, mesh=mesh)
@@ -343,108 +313,6 @@ def recompute_peel(spec: GraphSpec, st: GraphState, peel: jax.Array,
     return (jnp.where(st.active, phi, 0),
             PeelStats(waves, kills, jnp.int32(0),
                       jnp.sum(peel, dtype=jnp.int32)))
-
-
-@partial(jax.jit, static_argnames=("spec", "method"))
-def _profiled_wave(spec: GraphSpec, st: GraphState, frozen, fphi, alive, phi,
-                   k, method: str = "sorted"):
-    """One wave of the recompute discipline as a standalone jitted step —
-    the exact ``recompute_peel`` body arithmetic, factored out so the
-    profiled loop can step it from the host and time each wave.  Returns
-    ``(alive, phi, k, kill_count)``."""
-    qual = alive | (frozen & (fphi >= k))
-    if method == "bitmap":
-        sup = support_all_bitmap(spec, st, qual)
-    else:
-        sup = support_all(spec, st, qual)
-    kill = alive & (sup < k - 2)
-    any_kill = jnp.any(kill)
-    phi = jnp.where(kill, k - 1, phi)
-    alive = alive & ~kill
-    min_sup = jnp.min(jnp.where(alive, sup, _INF))
-    j2 = jnp.min(jnp.where(frozen & (fphi >= k), fphi, _INF)) + 1
-    k_jump = jnp.maximum(jnp.minimum(min_sup + 3, j2), k + 1)
-    k = jnp.where(any_kill, k, k_jump)
-    return alive, phi, k, jnp.sum(kill, dtype=jnp.int32)
-
-
-_PROBE_CACHE: dict = {}
-
-
-def _decision_probe(mesh, ax: str):
-    """Jitted, cached shard_map probe that runs exactly one packed 4-lane
-    decision ``pmin`` — the single per-wave collective of the sharded
-    engine — so the profiled loop can time the collective in isolation."""
-    key = (id(mesh), ax)
-    fn = _PROBE_CACHE.get(key)
-    if fn is None:
-        from jax.sharding import PartitionSpec as P
-
-        def local_fn(x):
-            """Per-shard body: one decision pmin over replicated lanes."""
-            s, f, d, w = _decision(x[0], x[1], x[2] > 0, x[3] > 0, ax)
-            return s + f + d.astype(jnp.int32) + w.astype(jnp.int32)
-
-        fn = jax.jit(jax.shard_map(local_fn, mesh=mesh, in_specs=(P(),),
-                                   out_specs=P(), check_vma=False))
-        _PROBE_CACHE[key] = fn
-    return fn
-
-
-def _profiled_peel(spec: GraphSpec, st: GraphState, peel_mask: jax.Array,
-                   method: str = "sorted", mesh=None):
-    """Host-stepped wave-profiled peel (``set_wave_profile``): the recompute
-    discipline stepped one jitted wave at a time so each wave can be timed
-    with a device sync (host timing inside the fused ``lax.while_loop``
-    engines is impossible).  phi is identical to every other engine —
-    wave discipline never changes the decomposition — and ``PeelStats``
-    reflects the recompute discipline (``deltas`` is 0).
-
-    Per wave: ``truss_peel_wave_seconds`` observes the synced wall time and
-    a ``peel.wave`` trace instant carries (wave, k, kills, dur_us).  Under a
-    ``mesh``, a standalone jitted probe of the packed decision ``pmin`` —
-    the sharded engine's one collective per wave (see ``_decision``) — is
-    timed alongside and ``truss_peel_wave_collective_share`` observes
-    probe/wave as the collective-share estimate (legitimate because the
-    sharded engine is bitwise-equal wave for wave, so the profiled wave is
-    the compute the sharded wave would do between collectives)."""
-    e_cap = spec.e_cap
-    peel_m = peel_mask & st.active
-    frozen = st.active & ~peel_m
-    fphi = st.phi
-    frontier = jnp.sum(peel_m, dtype=jnp.int32)
-
-    probe = None
-    if mesh is not None:
-        probe = _decision_probe(mesh, spec.shard_axis)
-        jax.block_until_ready(probe(jnp.zeros((4,), jnp.int32)))  # warm jit
-
-    alive, phi, k = peel_m, st.phi, jnp.int32(3)
-    # warm the step's jit cache so wave timings measure execution, not
-    # compilation (the step is pure, the discarded call changes nothing)
-    jax.block_until_ready(
-        _profiled_wave(spec, st, frozen, fphi, alive, phi, k, method=method))
-
-    waves = kills = 0
-    while bool(jnp.any(alive)) and waves < 8 * e_cap:
-        t0 = time.perf_counter()
-        alive, phi, k, nk = jax.block_until_ready(
-            _profiled_wave(spec, st, frozen, fphi, alive, phi, k,
-                           method=method))
-        dt = time.perf_counter() - t0
-        waves += 1
-        kills += int(nk)
-        _WAVE_S.observe(dt)
-        obs_trace.instant("peel.wave", wave=waves, k=int(k), kills=int(nk),
-                          dur_us=round(dt * 1e6, 1))
-        if probe is not None and dt > 0:
-            t1 = time.perf_counter()
-            jax.block_until_ready(probe(jnp.zeros((4,), jnp.int32)))
-            _WAVE_COLL.observe(
-                min(1.0, (time.perf_counter() - t1) / dt))
-    return (jnp.where(st.active, phi, 0),
-            PeelStats(jnp.int32(waves), jnp.int32(kills), jnp.int32(0),
-                      frontier))
 
 
 def _peel_bitmap(spec, st, peel, frozen, fphi, alive0, bitmap):

@@ -55,7 +55,6 @@ import time
 import numpy as np
 
 from ..cluster import QueryRouter, Replica, query_from_record
-from ..core.peel import set_wave_profile as _set_wave_profile
 from ..data.streams import READ, GraphUpdateStream, MixedWorkloadStream
 from ..data.synthetic import powerlaw_graph
 from ..faults import FaultyIO, RetryPolicy, seeded_schedule
@@ -368,10 +367,6 @@ def main(argv=None):
                          "postmortem bundle under DIR when the degradation "
                          "ladder fires (breaker open, quarantine, scrub or "
                          "SLO violation)")
-    ap.add_argument("--wave-profile", action="store_true",
-                    help="per-wave peel timing: host-stepped waves feed the "
-                         "truss_peel_wave_seconds histogram (adds one "
-                         "device sync per wave — measurement mode)")
     ap.add_argument("--profile-dir", default=None, metavar="DIR",
                     help="arm jax.profiler captures around the flush and "
                          "decompose regions; traces land under DIR")
@@ -412,8 +407,6 @@ def main(argv=None):
         profiling.configure(args.profile_dir)
     if args.postmortem_dir is not None:
         flightrec.FLIGHT.configure(args.postmortem_dir)
-    if args.wave_profile:
-        _set_wave_profile(True)
     writer = None
     if args.trace_jsonl is not None:
         proc = ("replica" if args.replica_of else
@@ -442,7 +435,6 @@ def main(argv=None):
         if metrics_server is not None:
             metrics_server.stop()
         profiling.configure(None)
-        _set_wave_profile(False)
 
 
 def _dispatch(args, ks, rng, slo_engine, cell):

@@ -1,7 +1,7 @@
 """Observability plane: metrics registry, structured trace, exposition.
 
-Zero-dependency (stdlib + the rest of ``repro``) telemetry threaded
-through every hot path of the serving stack:
+Telemetry on the stdlib, the rest of ``repro`` and ``jax.profiler``,
+threaded through every hot path of the serving stack:
 
 * ``obs.metrics`` — process-wide named counters/gauges/histograms with
   ``snapshot()``/``reset()`` (WAL append/fsync latency, flush size, peel
@@ -9,12 +9,14 @@ through every hot path of the serving stack:
   decisions);
 * ``obs.trace`` — ring-buffered span events with injectable clocks, JSONL
   (``TraceWriter``) and Chrome ``trace_event`` export, so the pipelined
-  flush→dispatch→land overlap is visually inspectable;
+  flush→dispatch→land overlap is visually inspectable; every span is also
+  a ``jax.profiler.TraceAnnotation``, so it lands in any profiler trace;
 * ``obs.expo`` — Prometheus text rendering, a round-trip parser, and the
   stdlib HTTP ``MetricsServer`` behind ``serve_truss --metrics-port``
   (``/metrics`` + the SLO-backed ``/healthz``);
 * ``obs.profiling`` — gated ``jax.profiler`` start/stop hooks around flush
-  and decompose (``--profile-dir``);
+  and decompose (``--profile-dir``), and the compile counter
+  (``truss_xla_compiles_total``, ``xla.compile`` spans);
 * ``obs.slo`` — declarative objectives evaluated with multi-window
   burn-rate over the live registry (``truss_slo_*``, ``stats()["slo"]``,
   ``/healthz``);

@@ -15,17 +15,40 @@ the disk before it filled a timeline).  A trace that fails to start does
 not stop the region it wraps, but it is counted
 (``truss_profiler_start_failures_total``) so a measurement run can refuse
 a trace that silently never happened.
+
+Compilations are counted here too: one ``jax.monitoring`` listener, set up
+at import, advances ``truss_xla_compiles_total`` for every backend compile
+and records it as an ``xla.compile`` span that ends when JAX reports it and
+lasts as long as JAX timed it — so a stall caused by a recompile is named
+in the span trace, under the span that triggered it.
 """
 from __future__ import annotations
 
 import os
 from contextlib import contextmanager
 
+import jax
+
 from . import metrics, trace
 
 _START_FAILED = metrics.counter(
     "truss_profiler_start_failures_total",
     "jax.profiler traces that failed to start (the region ran untraced)")
+_COMPILES = metrics.counter(
+    "truss_xla_compiles_total",
+    "XLA backend compilations in this process (jax.monitoring)")
+
+#: the ``jax.monitoring`` duration event of one backend compilation
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+def _on_compile_event(event: str, duration_secs: float, **_):
+    if event == COMPILE_EVENT:
+        _COMPILES.inc()
+        trace.TRACER.complete("xla.compile", int(duration_secs * 1e9))
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_compile_event)
 
 _DIR: str | None = None
 _MAX = 8
@@ -57,8 +80,6 @@ def profile_region(name: str):
     if not is_configured() or _ACTIVE:
         yield
         return
-    import jax
-
     path = os.path.join(_DIR, f"{name}-{_COUNT}")
     _COUNT += 1
     _ACTIVE = True

@@ -21,7 +21,14 @@ Exports:
   complete events, microsecond timestamps) loadable in ``chrome://tracing``
   / Perfetto; see ``docs/OBSERVABILITY.md`` for how to read one.
 
-Recording is a no-op (a shared null span) while ``repro.obs`` is disabled.
+Every span is also a ``jax.profiler.TraceAnnotation`` of the same name,
+opened and closed with it, so a JAX profiler trace (the benchmark's, or
+``serve_truss --profile-dir``) carries the spans on its own host clock,
+beside the device ops they dispatch or wait for.  With no profiler trace
+running the annotation is skipped after one check.
+
+Recording is a no-op (a shared null span, no annotation) while
+``repro.obs`` is disabled.
 """
 from __future__ import annotations
 
@@ -30,6 +37,8 @@ import os
 import time
 from contextlib import contextmanager
 from typing import NamedTuple
+
+from jax.profiler import TraceAnnotation
 
 from . import metrics as _metrics
 from .state import STATE
@@ -113,9 +122,11 @@ _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    """A live (entered, not yet exited) span; records itself on exit."""
+    """A live (entered, not yet exited) span; records itself on exit and
+    mirrors itself into any running profiler trace."""
 
-    __slots__ = ("_tracer", "name", "attrs", "seq", "parent", "depth", "t0")
+    __slots__ = ("_tracer", "name", "attrs", "seq", "parent", "depth", "t0",
+                 "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: dict | None):
         self._tracer = tracer
@@ -129,12 +140,18 @@ class _Span:
         self.seq = tr._seq
         tr._seq += 1
         tr._stack.append(self.seq)
+        self._ann = None
+        if TraceAnnotation.is_enabled():
+            self._ann = TraceAnnotation(self.name)
+            self._ann.__enter__()
         self.t0 = tr.clock()
         return self
 
     def __exit__(self, *exc):
         tr = self._tracer
         t1 = tr.clock()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
         tr._stack.pop()
         tr._record(SpanEvent(self.seq, self.parent, self.depth, self.name,
                              self.t0, t1 - self.t0, self.attrs))
@@ -184,12 +201,19 @@ class Tracer:
     def instant(self, name: str, **attrs):
         """Record a zero-duration event at the current time/nesting (e.g.
         an admission-control shed)."""
+        self.complete(name, 0, **attrs)
+
+    def complete(self, name: str, dur_ns: int, **attrs):
+        """Record a span that ends now and lasted ``dur_ns`` — work timed
+        by someone else and reported when it finished (a compilation
+        reported by ``jax.monitoring``), nested under the open span."""
         if not STATE.enabled:
             return
         parent = self._stack[-1] if self._stack else -1
         seq, self._seq = self._seq, self._seq + 1
+        dur_ns = int(dur_ns)
         self._record(SpanEvent(seq, parent, len(self._stack), name,
-                               self.clock(), 0, attrs or None))
+                               self.clock() - dur_ns, dur_ns, attrs or None))
 
     def _record(self, ev: SpanEvent):
         ctx = self._ctx

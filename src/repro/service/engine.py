@@ -119,6 +119,16 @@ _PEEL_KILLS = obs_metrics.counter(
     "truss_peel_kills_total", "edges assigned a phi by the peel engine")
 _PEEL_DELTAS = obs_metrics.counter(
     "truss_peel_deltas_total", "scatter-subtracted support updates")
+_AFFECTED_N = obs_metrics.counter(
+    "truss_affected_edges_total",
+    "edges entering the re-peel (the fused batch's affected set |A|)")
+_CLOSURE_ITERS_N = obs_metrics.counter(
+    "truss_closure_iterations_total",
+    "affected-set closure while-loop iterations (fused batch maintain)")
+_CLOSURE_UNFILTERED_N = obs_metrics.counter(
+    "truss_closure_unfiltered_total",
+    "generations whose closure ran without the range filter (mixed-batch "
+    "fallback)")
 _Q_DEPTH = obs_metrics.gauge(
     "truss_pipeline_queue_depth",
     "acked-but-unapplied records queued (pipeline mode)")
@@ -319,10 +329,11 @@ class TrussService:
 
     def _record_commit_metrics(self, n: int, dur_s: float | None) -> dict:
         """Registry side of one committed generation; returns the peel
-        stats dict for the committed snapshot.  Peel counters advance only
-        when ``last_peel_stats`` is a *new* object — a netted no-op commit
-        leaves the previous generation's stats in place and must not
-        double-count them."""
+        stats dict for the committed snapshot.  Peel and closure counters
+        advance only when ``last_peel_stats`` is a *new* object — a netted
+        no-op commit leaves the previous generation's stats in place and
+        must not double-count them.  Every commit path calls this after
+        the generation has landed, so the reads below wait for nothing."""
         _FLUSH_N.inc()
         _FLUSH_SIZE.observe(n)
         if dur_s is not None:
@@ -333,6 +344,9 @@ class TrussService:
             _PEEL_WAVES.inc(d["waves"])
             _PEEL_KILLS.inc(d["kills"])
             _PEEL_DELTAS.inc(d["deltas"])
+            _AFFECTED_N.inc(d["frontier"])
+            _CLOSURE_ITERS_N.inc(d["closure_iters"])
+            _CLOSURE_UNFILTERED_N.inc(d["closure_unfiltered"])
             self._stats_seen = ps
         return d
 
@@ -729,31 +743,35 @@ class TrussService:
         frontier covering a suspect state) and the caller degrades.  A
         failed ``commit.json`` write is tolerated: the frontier file is
         advisory (replicas just lag until the next successful publish),
-        losing it must not fail an already-landed generation."""
-        self.gen = gen
-        self._applied_wal += n
-        peel = self._record_commit_metrics(n, dur_s)
-        self._capture_committed(peel)
-        obs_flightrec.FLIGHT.note("commit", gen=self.gen, n=n,
-                                  wal=self._applied_wal)
-        obs_flightrec.FLIGHT.tick()
-        if self.slo is not None:
-            self.slo.evaluate()
-        if self.store is not None:
-            try:
-                self.store.publish_commit(self.gen, self._applied_wal)
-            except OSError as exc:
-                self.breaker.record_failure()
-                _BREAKER_G.set(self.breaker.state_code)
-                obs_trace.instant("commit.publish_failed",
-                                  gen=self.gen, err=repr(exc)[:120])
-        # a full commit is the definitive success signal: close the breaker
-        # and flip any retried quarantined generations to recovered (skipped
-        # mid-heal — the heal reports success itself once the replay is done)
-        if not self._needs_heal and (
-                self._degraded_reason is not None
-                or self.breaker.state != CLOSED or self.breaker.failures):
-            self._recovered()
+        losing it must not fail an already-landed generation.  The
+        ``gen.commit`` span covers all of it: state read-back and
+        invariants, counters, flight recorder, SLO, ``commit.json``."""
+        with obs_trace.span("gen.commit", gen=gen, n=n):
+            self.gen = gen
+            self._applied_wal += n
+            peel = self._record_commit_metrics(n, dur_s)
+            self._capture_committed(peel)
+            obs_flightrec.FLIGHT.note("commit", gen=self.gen, n=n,
+                                      wal=self._applied_wal)
+            obs_flightrec.FLIGHT.tick()
+            if self.slo is not None:
+                self.slo.evaluate()
+            if self.store is not None:
+                try:
+                    self.store.publish_commit(self.gen, self._applied_wal)
+                except OSError as exc:
+                    self.breaker.record_failure()
+                    _BREAKER_G.set(self.breaker.state_code)
+                    obs_trace.instant("commit.publish_failed",
+                                      gen=self.gen, err=repr(exc)[:120])
+            # a full commit is the definitive success signal: close the
+            # breaker and flip any retried quarantined generations to
+            # recovered (skipped mid-heal — the heal reports success itself
+            # once the replay is done)
+            if not self._needs_heal and (
+                    self._degraded_reason is not None
+                    or self.breaker.state != CLOSED or self.breaker.failures):
+                self._recovered()
 
     def _complete(self, wait: bool = True) -> bool:
         """Land the in-flight generation.  ``wait=False`` only completes a
@@ -855,20 +873,21 @@ class TrussService:
                 f"service degraded ({self._degraded_reason or 'breaker open'})")
         if self.pipeline:
             return self._submit_many_pipelined(ups)
-        view = set(self._view)
-        tagged = []
-        gen, pend = self.gen, len(self._pending)
-        for op, a, b in ups:
-            key = self._admit(view, op, a, b)
-            if op == OP_INSERT:
-                view.add(key)
-            else:
-                view.discard(key)
-            tagged.append((gen + 1, op, a, b))
-            pend += 1
-            if pend >= self.flush_every:  # mirror submit's auto-flush
-                gen += 1
-                pend = 0
+        with obs_trace.span("service.admit", n=len(ups)):
+            view = set(self._view)
+            tagged = []
+            gen, pend = self.gen, len(self._pending)
+            for op, a, b in ups:
+                key = self._admit(view, op, a, b)
+                if op == OP_INSERT:
+                    view.add(key)
+                else:
+                    view.discard(key)
+                tagged.append((gen + 1, op, a, b))
+                pend += 1
+                if pend >= self.flush_every:  # mirror submit's auto-flush
+                    gen += 1
+                    pend = 0
         t0 = time.perf_counter()
         for g in dict.fromkeys(t[0] for t in tagged):
             self._annotate_gen(g)
@@ -897,21 +916,22 @@ class TrussService:
         pre-computed tags are authoritative — the adaptive target may
         retune mid-walk (a completion inside ``_pump`` does that) — so
         seals are driven by tag changes, not by re-reading the threshold."""
-        view = set(self._view)
-        tagged = []
-        gen, cnt = self._open_gen, self._open_count
-        target = self._flush_target  # frozen for the simulation
-        for op, a, b in ups:
-            key = self._admit(view, op, a, b)
-            if op == OP_INSERT:
-                view.add(key)
-            else:
-                view.discard(key)
-            tagged.append((gen, op, a, b))
-            cnt += 1
-            if cnt >= target:
-                gen += 1
-                cnt = 0
+        with obs_trace.span("service.admit", n=len(ups)):
+            view = set(self._view)
+            tagged = []
+            gen, cnt = self._open_gen, self._open_count
+            target = self._flush_target  # frozen for the simulation
+            for op, a, b in ups:
+                key = self._admit(view, op, a, b)
+                if op == OP_INSERT:
+                    view.add(key)
+                else:
+                    view.discard(key)
+                tagged.append((gen, op, a, b))
+                cnt += 1
+                if cnt >= target:
+                    gen += 1
+                    cnt = 0
         t0 = time.perf_counter()
         for g in dict.fromkeys(t[0] for t in tagged):
             self._annotate_gen(g)
@@ -1395,6 +1415,7 @@ class TrussService:
             "engine_fallbacks": reg.value("truss_engine_fallback_total"),
             "self_heals": reg.value("truss_self_heal_total"),
             "degraded_sheds": reg.value("truss_degraded_shed_total"),
+            "xla_compiles": reg.value("truss_xla_compiles_total"),
         }
         if self.pipeline:
             out["pipeline"] = {

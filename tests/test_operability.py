@@ -1,6 +1,6 @@
 """Tier-1 tests for the operability plane (ISSUE-9).
 
-Four load-bearing properties:
+Three load-bearing properties:
 
 1. **SLO state machine** — multi-window burn rates computed from real
    registry snapshots under an injected clock walk ok -> burning ->
@@ -14,8 +14,6 @@ Four load-bearing properties:
    round trip recorded by two processes merges into one Chrome trace where
    a single ``trace_id`` spans all three components, and every per-process
    track is well-nested.
-4. **Wave profiling** — the host-stepped profiled peel returns bitwise the
-   same phi as the fused engines while populating the per-wave histogram.
 """
 import json
 import os
@@ -26,9 +24,6 @@ import numpy as np
 import pytest
 
 from repro.cluster import QueryRouter, Replica
-from repro.core.graph import GraphSpec, from_edge_list
-from repro.core.peel import (peel as run_peel, set_wave_profile,
-                             wave_profile_enabled)
 from repro.faults import FaultyIO, seeded_schedule
 from repro.obs import flightrec, merge, metrics
 from repro.obs import trace as obs_trace
@@ -414,31 +409,3 @@ def test_wal_trace_annotations_round_trip(tmp_path):
     open(store.wal_path, "wb").write(bad)
     again = TrussStore(str(tmp_path), readonly=True)
     assert again.read_trace_annotations().get(1) == "ab" * 16
-
-
-# -- wave-level profiling -----------------------------------------------------
-
-def test_wave_profile_matches_fused_engines():
-    rng = np.random.default_rng(4)
-    n = 40
-    edges = np.array(sorted({(min(u, v), max(u, v))
-                             for u, v in rng.integers(0, n, (200, 2))
-                             if u != v}), np.int32)
-    spec = GraphSpec(n_nodes=n, e_cap=256, d_max=64)
-    st = from_edge_list(spec, edges)
-    phi0, s0 = run_peel(spec, st, st.active)
-    before = metrics.REGISTRY.snapshot().get("truss_peel_wave_seconds")
-    n_before = (sum(v["count"] for v in before["values"].values())
-                if before else 0)
-    set_wave_profile(True)
-    try:
-        assert wave_profile_enabled()
-        phi1, s1 = run_peel(spec, st, st.active)
-    finally:
-        set_wave_profile(False)
-    assert np.array_equal(np.asarray(phi0), np.asarray(phi1))
-    assert int(s1.waves) == int(s0.waves)
-    assert int(s1.kills) == int(s0.kills)
-    snap = metrics.REGISTRY.snapshot()["truss_peel_wave_seconds"]
-    n_after = sum(v["count"] for v in snap["values"].values())
-    assert n_after == n_before + int(s1.waves)
