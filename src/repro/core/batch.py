@@ -14,7 +14,13 @@ Algorithms for Truss Decomposition"), in three fused stages:
    edges of a deleted edge must be enumerated before the triangles vanish),
    insertion stats on the post-update graph (so triangles formed between two
    edges of the same batch are seen).  A BFS closure over triangle adjacency
-   collects every edge that could transitively change.
+   collects every edge that could transitively change.  Its partner lookup
+   follows what the call is given (``closure_probes_bitmap``): with a
+   replicated post-update adjacency bitmap (the bitmap method) a frontier
+   edge's partner edges are one bit test per adjacency slot in its
+   endpoints' bitmap rows (``peel.chunk_partner_edges_bitmap``); with no
+   bitmap (``sorted``) or a node-partitioned one it binary-searches the
+   sorted rows (``peel.chunk_partners``).  Both admit the same set.
 
    Range soundness for batches: the per-update ranges compose across a
    *homogeneous* batch (insert-only or delete-only) because partner sets
@@ -61,13 +67,24 @@ import jax.numpy as jnp
 from .graph import (GraphSpec, GraphState, apply_edge_batch_struct,
                     lookup_edge, triangle_partners)
 from .maintenance import _NEG, _POS
-from .peel import chunk_partners, gather_phi, peel as run_peel, scatter_or
+from .peel import (chunk_partner_edges_bitmap, chunk_partners, gather_phi,
+                   peel as run_peel, scatter_or)
 
 
 class _ExpandCarry(NamedTuple):
     affected: jax.Array   # bool[E_cap] — the affected set A so far
     frontier: jax.Array   # bool[E_cap] — edges whose partners are unexplored
     it: jax.Array
+
+
+def closure_probes_bitmap(spec: GraphSpec, bitmap) -> bool:
+    """Whether ``batch_maintain``'s closure tests partner edges against
+    ``bitmap`` rather than binary-searching the sorted adjacency rows.  It
+    needs the full bitmap on every device: under ``partition="nodes"``
+    each device holds one word slab, and a probe would pull slabs across
+    chips.  Fixed when the program is traced; the service counts the
+    generations it holds for (``truss_closure_bitmap_probe_total``)."""
+    return bitmap is not None and spec.partition == "replicated"
 
 
 @partial(jax.jit, static_argnames=("spec", "batch", "method", "engine",
@@ -85,7 +102,11 @@ def batch_maintain(spec: GraphSpec, st: GraphState,
     insertions must be disjoint, structurally valid edge sets (host-side
     netting in ``DynamicGraph.apply_batch`` guarantees this).  ``bitmap``,
     when given (bitmap method), must be the adjacency bitmap of the
-    POST-update active set (``DynamicGraph`` maintains it incrementally).
+    POST-update active set (``DynamicGraph`` maintains it incrementally);
+    when it is full-width on every device (``partition="replicated"``) the
+    closure probes it for partner edges instead of binary-searching the
+    sorted rows (``closure_probes_bitmap``) — same affected set, fewer
+    per-element gathers.
     ``mesh`` (static, hashable) runs the frozen-boundary re-peel
     edge-sharded over ``mesh[spec.shard_axis]`` — the structural pass and
     affected-set closure are O(B·D) one-shot work and stay replicated; the
@@ -211,16 +232,22 @@ def batch_maintain(spec: GraphSpec, st: GraphState,
 
     with jax.named_scope("closure"):
         # ---- BFS closure over triangle adjacency -------------------------
+        probe = closure_probes_bitmap(spec, bitmap)
+
         def exp_cond(c: _ExpandCarry):
             return jnp.any(c.frontier) & (c.it < e_cap)
 
         def exp_body(c: _ExpandCarry):
             idx = jnp.nonzero(c.frontier, size=batch, fill_value=e_cap)[0]
             live = idx < e_cap
-            p1, p2, tval = chunk_partners(spec, st1, idx, st1.active)
+            if probe:
+                sides = chunk_partner_edges_bitmap(spec, st1, idx, bitmap)
+            else:
+                p1, p2, tval = chunk_partners(spec, st1, idx, st1.active)
+                sides = ((p1, tval), (p2, tval))
             nxt = jnp.zeros((e_cap,), bool)
-            nxt = scatter_or(nxt, p1, admissible(p1, tval))
-            nxt = scatter_or(nxt, p2, admissible(p2, tval))
+            for ids, msk in sides:
+                nxt = scatter_or(nxt, ids, admissible(ids, msk))
             nxt = nxt & ~c.affected
             processed = scatter_or(jnp.zeros((e_cap,), bool), idx, live)
             return _ExpandCarry(c.affected | nxt,

@@ -86,8 +86,10 @@ class DynamicGraph:
             mesh=self.mesh)
         self.state = self.state._replace(phi=phi)
         # every maintenance path records a PeelStats — never None (the
-        # initial decomposition's peel counts as the first one)
+        # initial decomposition's peel counts as the first one) — and
+        # whether it came from a fused closure that probed the bitmap
         self.last_peel_stats = stats
+        self.last_closure_probed = False
         self.index = TrussIndex(self.spec, tracked_ks)
         # Host mirror of the present-edge set, kept in sync by every update
         # path so batch netting never forces a device->host transfer.
@@ -121,6 +123,7 @@ class DynamicGraph:
         g._bitmap = None
         g._set_memory_gauges()
         g.last_peel_stats = EMPTY_STATS  # phi trusted as-is: no peel ran
+        g.last_closure_probed = False
         g.index = TrussIndex(g.spec, tracked_ks)
         act = np.asarray(g.state.active)
         edges = np.asarray(g.state.edges)[act]
@@ -234,6 +237,7 @@ class DynamicGraph:
         # Algorithm-2 path: no peel ran — record the empty stats rather than
         # None so consumers (service stats, telemetry) never need guards
         self.last_peel_stats = EMPTY_STATS
+        self.last_closure_probed = False
         _PROGRESSIVE_N.inc()
         # Other edges' phi moves only inside the Theorem-2 range, but the
         # inserted edge itself joins (and can merge components of) every
@@ -248,6 +252,7 @@ class DynamicGraph:
         self.state = maintenance.delete_edge_maintain(self.spec, self.state, a, b)
         # Algorithm-1 path: no peel ran — empty stats, never None
         self.last_peel_stats = EMPTY_STATS
+        self.last_closure_probed = False
         _PROGRESSIVE_N.inc()
         # The deleted edge leaves (and can split components of) every level
         # k <= phi(e), not just the Theorem-1 phi range.
@@ -350,6 +355,8 @@ class DynamicGraph:
             self._bitmap = None
             raise
         self.last_peel_stats = stats
+        self.last_closure_probed = batch.closure_probes_bitmap(self.spec,
+                                                               self._bitmap)
         self._present = cur
         if defer_sync:
             # async-dispatch mode: the re-peel is in flight; hand the device
@@ -438,6 +445,7 @@ class DynamicGraph:
             bitmap=self._bitmap_cache(), mesh=self.mesh)
         self.state = self.state._replace(phi=phi)
         self.last_peel_stats = stats
+        self.last_closure_probed = False
         self.index = TrussIndex(self.spec, self.index.tracked)
         self.index.invalidate_all()
 

@@ -129,8 +129,9 @@ def chunk_partners(spec: GraphSpec, st: GraphState, idx: jax.Array,
     ids and a validity mask requiring a live row AND both partners in
     ``alive`` — i.e. ``tval`` marks exactly the triangles of the chunk edges
     that exist in the ``alive`` subgraph.  This is the one wave primitive
-    behind the delta-peel engine, Algorithm 1/2 localSupport frontiers, and
-    the batch engine's affected-set closure.
+    behind the delta-peel engine and Algorithm 1/2 localSupport frontiers;
+    the batch engine's affected-set closure uses it only when it has no
+    replicated adjacency bitmap (``chunk_partner_edges_bitmap`` otherwise).
     """
     live = idx < spec.e_cap
     idxc = jnp.minimum(idx, spec.e_cap - 1)
@@ -140,6 +141,38 @@ def chunk_partners(spec: GraphSpec, st: GraphState, idx: jax.Array,
     tval = (tval & live[:, None]
             & gather_mask(alive, p1) & gather_mask(alive, p2))
     return p1, p2, tval
+
+
+def chunk_partner_edges_bitmap(spec: GraphSpec, st: GraphState,
+                               idx: jax.Array, bitmap: jax.Array):
+    """Partner edges of a compacted chunk of edge slots, by bitmap probe.
+
+    ``idx`` is a fixed-size batch of edge slots (sentinel ``e_cap`` on dead
+    rows) and ``bitmap`` the full-width ``uint32[N, W]`` adjacency bitmap of
+    the edge set ``st`` holds (``graph.partial_bitmap``'s layout: node w is
+    bit ``w % 32`` of word ``w // 32``).  For a chunk edge (u, v), slot j of
+    u's row names a partner edge iff its neighbor ``nbr[u, j]`` is a
+    neighbor of v: one bit test in row ``bitmap[v]``, no search, and the
+    bit also says that edge is present.  Returns ``((eid_u, val_u),
+    (eid_v, val_v))``, each of shape [C, D]: the partner *edges* on u's
+    side and on v's side, unpaired — slot (i, j) of one side and of the
+    other need not close the same triangle, so this answers "which edges
+    share a triangle with the chunk", not "which triangles".
+    """
+    n = spec.n_nodes
+    live = idx < spec.e_cap
+    idxc = jnp.minimum(idx, spec.e_cap - 1)
+    u = jnp.minimum(st.edges[idxc, 0], n - 1)
+    v = jnp.minimum(st.edges[idxc, 1], n - 1)
+
+    def side(a, rows):
+        w = st.nbr[a]                                   # [C, D]
+        wc = jnp.minimum(w, n - 1)                      # sentinel N clamped
+        words = jnp.take_along_axis(rows, wc >> 5, axis=1)   # word w // 32
+        bit = (words >> (wc & 31).astype(jnp.uint32)) & jnp.uint32(1)
+        return st.eid[a], live[:, None] & (w < n) & (bit == 1)
+
+    return side(u, bitmap[v]), side(v, bitmap[u])
 
 
 # ---------------------------------------------------------------------------

@@ -129,6 +129,9 @@ _CLOSURE_UNFILTERED_N = obs_metrics.counter(
     "truss_closure_unfiltered_total",
     "generations whose closure ran without the range filter (mixed-batch "
     "fallback)")
+_CLOSURE_PROBE_N = obs_metrics.counter(
+    "truss_closure_bitmap_probe_total",
+    "generations whose closure tested partners against the adjacency bitmap")
 _Q_DEPTH = obs_metrics.gauge(
     "truss_pipeline_queue_depth",
     "acked-but-unapplied records queued (pipeline mode)")
@@ -347,6 +350,7 @@ class TrussService:
             _AFFECTED_N.inc(d["frontier"])
             _CLOSURE_ITERS_N.inc(d["closure_iters"])
             _CLOSURE_UNFILTERED_N.inc(d["closure_unfiltered"])
+            _CLOSURE_PROBE_N.inc(int(self.graph.last_closure_probed))
             self._stats_seen = ps
         return d
 
@@ -1408,6 +1412,8 @@ class TrussService:
             "fsyncs": reg.value("truss_wal_fsync_total"),
             "wal_records": reg.value("truss_wal_append_records_total"),
             "peel_waves": reg.value("truss_peel_waves_total"),
+            "closure_bitmap_probes":
+                reg.value("truss_closure_bitmap_probe_total"),
             "sheds": reg.value("truss_pipeline_shed_total"),
             "progressive_updates":
                 reg.value("truss_progressive_updates_total"),

@@ -179,3 +179,80 @@ def test_vectorized_struct_matches_sequential():
         # mapping triangle enumeration depends on) must match exactly too
         assert np.array_equal(np.asarray(st2.eid), np.asarray(ref.eid)), trial
         assert np.array_equal(np.asarray(st2.deg), np.asarray(ref.deg)), trial
+
+
+# -- affected-set closure: bitmap probe == sorted-row search -------------------
+PROBE_N = 64        # a multiple of 32: the sentinel N lands one word past W
+PROBE_D = 24        # node 0's row is filled to d_max
+PROBE_E = 512
+PROBE_CHUNK = 16    # closure chunk: several iterations, a last one with
+                    # sentinel rows
+
+
+def _probe_graph(seed):
+    rng = np.random.default_rng(seed)
+    hub = [(0, j) for j in range(1, PROBE_D + 1)]
+    rest = [(i, j) for i in range(1, PROBE_N) for j in range(i + 1, PROBE_N)
+            if rng.random() < 0.12]
+    return hub + rest, rng
+
+
+@pytest.mark.parametrize("kind", ["mixed", "insert", "delete"])
+def test_closure_bitmap_probe_matches_search(kind):
+    """The closure's bitmap probe (a replicated post-update bitmap given)
+    admits exactly the set the sorted-row search admits: the same |A|,
+    iterations, range and fallback flag, and phi equal to the oracle's.
+    Node 0's row is full, so it holds no sentinel; the batch leaves it
+    alone."""
+    import jax.numpy as jnp
+
+    from repro.core import decompose
+    from repro.core.batch import batch_maintain, closure_probes_bitmap
+    from repro.core.graph import build_bitmap
+
+    edges, rng = _probe_graph(7)
+    present = set(edges)
+    absent = [(i, j) for i in range(1, PROBE_N) for j in range(i + 1, PROBE_N)
+              if (i, j) not in present]
+    movable = sorted(e for e in edges if e[0] != 0)
+    n_del, n_ins = {"mixed": (4, 4), "insert": (0, 8), "delete": (8, 0)}[kind]
+    dels = [movable[i] for i in rng.choice(len(movable), n_del, replace=False)]
+    inss = [absent[i] for i in rng.choice(len(absent), n_ins, replace=False)]
+    post = (present - set(dels)) | set(inss)
+
+    spec = GraphSpec(n_nodes=PROBE_N, d_max=PROBE_D, e_cap=PROBE_E)
+
+    def pad(pairs, bsz=8):
+        a, b, m = (np.zeros(bsz, np.int32), np.zeros(bsz, np.int32),
+                   np.zeros(bsz, bool))
+        for i, (x, y) in enumerate(pairs):
+            a[i], b[i], m[i] = x, y, True
+        return jnp.asarray(a), jnp.asarray(b), jnp.asarray(m)
+
+    def run(method):
+        st = from_edge_list(spec, np.asarray(edges))
+        st = st._replace(phi=decompose(spec, st))
+        bitmap = None
+        if method == "bitmap":
+            st_post = from_edge_list(spec, np.asarray(sorted(post)))
+            bitmap = build_bitmap(spec, st_post, st_post.active)
+        probed = closure_probes_bitmap(spec, bitmap)
+        st1, lo, hi, stats = batch_maintain(
+            spec, st, *pad(dels), *pad(inss), batch=PROBE_CHUNK,
+            method=method, bitmap=bitmap)
+        act = np.asarray(st1.active)
+        phi = {(int(a), int(b)): int(p) for (a, b), p in
+               zip(np.asarray(st1.edges)[act], np.asarray(st1.phi)[act])}
+        assert int(np.asarray(st1.deg)[0]) == PROBE_D
+        return probed, phi, (int(lo), int(hi)), stats
+
+    probe, phi_p, range_p, s_p = run("bitmap")
+    search, phi_s, range_s, s_s = run("sorted")
+    assert probe and not search
+    assert phi_p == phi_s == _scratch_phi(post, n=PROBE_N)
+    assert range_p == range_s
+    for field in ("frontier", "closure_iters", "closure_unfiltered"):
+        assert int(getattr(s_p, field)) == int(getattr(s_s, field)), field
+    assert int(s_p.closure_unfiltered) == (kind == "mixed")
+    # some chunk ran with sentinel rows
+    assert int(s_p.closure_iters) * PROBE_CHUNK > int(s_p.frontier) > 0

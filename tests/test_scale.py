@@ -343,6 +343,43 @@ print("ok")
 """, devices=devices)
 
 
+def test_partitioned_closure_searches_and_matches_replicated():
+    """Under ``partition="nodes"`` each device holds one word slab of the
+    bitmap, so the fused closure keeps the sorted-row search; the
+    replicated graph probes its full bitmap.  Both reach the same phi and
+    every ``PeelStats`` field, the closure's included."""
+    run_py("""
+from repro.core import DynamicGraph, oracle
+from repro.core.batch import closure_probes_bitmap
+from repro.launch.mesh import make_shard_mesh
+
+N = 40
+BASE = [(i, j) for i in range(N) for j in range(i + 1, N) if (i * 7 + j) % 4 == 0]
+present = set(BASE)
+absent = [(i, j) for i in range(N) for j in range(i + 1, N)
+          if (i, j) not in present]
+batches = [[(0, *e) for e in BASE[:6]] + [(1, *e) for e in absent[:6]],
+           [(1, *e) for e in absent[6:14]],
+           [(0, *e) for e in BASE[6:14]]]
+g1 = DynamicGraph(N, BASE, support_method="bitmap")
+g2 = DynamicGraph(N, BASE, support_method="bitmap", mesh=make_shard_mesh(2),
+                  partition="nodes")
+assert closure_probes_bitmap(g1.spec, g1._bitmap_cache())
+assert not closure_probes_bitmap(g2.spec, g2._bitmap_cache())
+orc = oracle.Oracle(N, BASE)
+for batch in batches:
+    g1.apply_batch(batch, strategy="fused")
+    g2.apply_batch(batch, strategy="fused")
+    orc.apply(batch)
+    assert g1.last_closure_probed and not g2.last_closure_probed
+    assert g1.phi_dict() == g2.phi_dict() == orc.phi
+    s1, s2 = g1.last_peel_stats, g2.last_peel_stats
+    assert [int(x) for x in s1] == [int(x) for x in s2], (s1, s2)
+    assert int(s1.closure_iters) >= 1
+print("ok")
+""", devices=2)
+
+
 # ---------------------------------------------------------------------------
 # hypothesis sweep: random update batches x partition modes (full lane)
 # ---------------------------------------------------------------------------

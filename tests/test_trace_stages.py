@@ -7,6 +7,8 @@
   ``truss_affected_edges_total`` and ``truss_closure_unfiltered_total``
   advance by what the generation's ``batch_maintain`` returned
   (``PeelStats.closure_iters``, ``closure_unfiltered``, ``frontier``);
+  ``truss_closure_bitmap_probe_total`` by one for each generation whose
+  closure probed the adjacency bitmap;
 * host spans — a serial flush records one span per host stage, nested as
   ``docs/OBSERVABILITY.md`` documents;
 * profiler clock — every ``repro.obs`` span is a ``TraceAnnotation`` in a
@@ -31,7 +33,7 @@ import pytest
 
 from repro import obs
 from repro.core import GraphSpec, decompose
-from repro.core.batch import batch_maintain
+from repro.core.batch import batch_maintain, closure_probes_bitmap
 from repro.core.graph import from_edge_list
 from repro.core.maintenance import OP_DELETE, OP_INSERT
 from repro.obs import metrics, stages, trace
@@ -159,6 +161,27 @@ def test_unfiltered_closure_counted_only_when_not_separable(tmp_path, mixed):
     assert svc.stats()["peel"]["closure_unfiltered"] == int(mixed)
     assert (metrics.REGISTRY.value("truss_closure_unfiltered_total")
             - before) == int(mixed)
+
+
+@pytest.mark.parametrize("method", ["bitmap", "sorted"])
+def test_bitmap_probe_counted_per_generation(method):
+    """A bitmap service's generation hands ``batch_maintain`` its
+    replicated post-update bitmap, so the closure probes it: the counter
+    advances by one.  A sorted service's closure searches the sorted rows
+    and leaves it alone."""
+    edges = _graph()
+    svc = TrussService(N, edges, d_max=D_MAX, e_cap=E_CAP, flush_every=4,
+                       strategy="fused", support_method=method)
+    probed = method == "bitmap"
+    assert closure_probes_bitmap(svc.graph.spec,
+                                 svc.graph._bitmap_cache()) is probed
+    before = metrics.REGISTRY.value("truss_closure_bitmap_probe_total")
+    svc.submit_many([(OP_INSERT, a, b) for a, b in _absent(edges)[:4]])
+    svc.flush()
+    assert svc.gen == 1
+    after = metrics.REGISTRY.value("truss_closure_bitmap_probe_total")
+    assert after - before == int(probed)
+    assert svc.stats()["counters"]["closure_bitmap_probes"] == after
 
 
 # -- host spans ------------------------------------------------------------------
