@@ -15,12 +15,16 @@ Algorithms for Truss Decomposition"), in three fused stages:
    insertion stats on the post-update graph (so triangles formed between two
    edges of the same batch are seen).  A BFS closure over triangle adjacency
    collects every edge that could transitively change.  Its partner lookup
-   follows what the call is given (``closure_probes_bitmap``): with a
-   replicated post-update adjacency bitmap (the bitmap method) a frontier
-   edge's partner edges are one bit test per adjacency slot in its
+   follows what the call is given (``closure_probes_bitmap``): with the
+   post-update adjacency bitmap (the bitmap method, either layout) a
+   frontier edge's partner edges are one bit test per adjacency slot in its
    endpoints' bitmap rows (``peel.chunk_partner_edges_bitmap``); with no
-   bitmap (``sorted``) or a node-partitioned one it binary-searches the
-   sorted rows (``peel.chunk_partners``).  Both admit the same set.
+   bitmap (``sorted``) it binary-searches the sorted rows
+   (``peel.chunk_partners``).  Both admit the same set.  Under
+   ``partition="nodes"`` the closure runs under ``shard_map``
+   (``_sharded_closure``): the edge state enters replicated once, and each
+   iteration all-gathers only the chunk's endpoint rows from the word
+   slabs.
 
    Range soundness for batches: the per-update ranges compose across a
    *homogeneous* batch (insert-only or delete-only) because partner sets
@@ -65,10 +69,15 @@ import jax
 import jax.numpy as jnp
 
 from .graph import (GraphSpec, GraphState, apply_edge_batch_struct,
-                    lookup_edge, triangle_partners)
+                    lookup_edge, state_sharding, triangle_partners)
 from .maintenance import _NEG, _POS
 from .peel import (chunk_partner_edges_bitmap, chunk_partners, gather_phi,
                    peel as run_peel, scatter_or)
+
+
+#: Frontier edges the closure expands per iteration (``batch_maintain``'s
+#: ``batch``)
+CLOSURE_CHUNK = 256
 
 
 class _ExpandCarry(NamedTuple):
@@ -79,12 +88,96 @@ class _ExpandCarry(NamedTuple):
 
 def closure_probes_bitmap(spec: GraphSpec, bitmap) -> bool:
     """Whether ``batch_maintain``'s closure tests partner edges against
-    ``bitmap`` rather than binary-searching the sorted adjacency rows.  It
-    needs the full bitmap on every device: under ``partition="nodes"``
-    each device holds one word slab, and a probe would pull slabs across
-    chips.  Fixed when the program is traced; the service counts the
-    generations it holds for (``truss_closure_bitmap_probe_total``)."""
-    return bitmap is not None and spec.partition == "replicated"
+    ``bitmap`` rather than binary-searching the sorted adjacency rows: for
+    any bitmap handed in, on either layout.  Under ``partition="nodes"``
+    each device holds one word slab, and the closure all-gathers the
+    chunk's endpoint rows from the slabs (``_sharded_closure``), so the
+    probe sees exactly the replicated bits.  Only ``sorted``, which keeps no
+    bitmap, searches.  Fixed when the program is traced; the service counts
+    the generations it holds for (``truss_closure_bitmap_probe_total``)."""
+    return bitmap is not None
+
+
+def exchange_bytes(spec: GraphSpec, waves: int, closure_iters: int) -> int:
+    """Bytes a generation of the node-partitioned bitmap layout puts
+    between chips, counted as the payload of its collectives from the
+    shapes: each peel wave psums ``int32[e_cap]`` partial supports
+    (``peel._partitioned_bitmap_peel``), and each closure iteration
+    all-gathers the chunk's two endpoint-row blocks, ``uint32[CLOSURE_CHUNK,
+    W]`` each (``_sharded_closure`` at ``batch_maintain``'s default
+    chunk).  The one-off replication of the edge state at the entry of
+    both, and what the partitioner inserts in ``ranges`` and
+    ``structural``, are not counted."""
+    return 4 * (waves * spec.e_cap
+                + closure_iters * 2 * CLOSURE_CHUNK * spec.n_words)
+
+
+def _admissible(active, phi, lo, hi, e_cap: int):
+    """The closure's admission test: partner ids (sentinel ``e_cap``) of
+    live edges whose phi lies in ``[lo, hi]``."""
+    act_pad = jnp.concatenate([active, jnp.zeros((1,), bool)])
+    phi_pad = jnp.concatenate([phi, jnp.zeros((1,), jnp.int32)])
+
+    def admissible(ids, msk):
+        idc = jnp.minimum(ids, e_cap)
+        p = phi_pad[idc]
+        return msk & (ids < e_cap) & act_pad[idc] & (p >= lo) & (p <= hi)
+
+    return admissible
+
+
+def _close(affected0, admissible, partners, e_cap: int, batch: int):
+    """BFS over triangle adjacency from ``affected0``: each iteration
+    expands up to ``batch`` frontier edges through ``partners(idx)`` (pairs
+    of ``(partner ids, mask)``) and admits what ``admissible`` passes.
+    Returns ``(affected, iterations)``."""
+    def exp_cond(c: _ExpandCarry):
+        return jnp.any(c.frontier) & (c.it < e_cap)
+
+    def exp_body(c: _ExpandCarry):
+        idx = jnp.nonzero(c.frontier, size=batch, fill_value=e_cap)[0]
+        live = idx < e_cap
+        sides = partners(idx)
+        nxt = jnp.zeros((e_cap,), bool)
+        for ids, msk in sides:
+            nxt = scatter_or(nxt, ids, admissible(ids, msk))
+        nxt = nxt & ~c.affected
+        processed = scatter_or(jnp.zeros((e_cap,), bool), idx, live)
+        return _ExpandCarry(c.affected | nxt,
+                            (c.frontier & ~processed) | nxt, c.it + 1)
+
+    out = jax.lax.while_loop(
+        exp_cond, exp_body,
+        _ExpandCarry(affected0, affected0, jnp.int32(0)))
+    return out.affected, out.it
+
+
+def _sharded_closure(spec: GraphSpec, st: GraphState, affected0, lo, hi,
+                     bitmap, *, batch: int, mesh):
+    """The closure over a word-sharded bitmap (``partition="nodes"``), under
+    ``shard_map`` so the partitioner never gathers the bitmap: the edge
+    state and the seeds enter replicated (one gather of the row-blocked
+    edge arrays per generation), every device runs the replicated loop,
+    and each iteration all-gathers the chunk's endpoint rows from the slabs
+    (``chunk_partner_edges_bitmap(axis=...)``).  Same affected set and
+    iteration count as the replicated probe."""
+    from jax.sharding import PartitionSpec as P
+
+    e_cap, ax = spec.e_cap, spec.shard_axis
+
+    def local_fn(edges, active, phi, nbr, eid, deg, affected0, lo, hi,
+                 slab):
+        lst = GraphState(edges, active, phi, nbr, eid, deg)
+        return _close(
+            affected0, _admissible(active, phi, lo, hi, e_cap),
+            lambda idx: chunk_partner_edges_bitmap(spec, lst, idx, slab,
+                                                   axis=ax),
+            e_cap, batch)
+
+    return jax.shard_map(local_fn, mesh=mesh,
+                         in_specs=(P(),) * 9 + (P(None, ax),),
+                         out_specs=(P(), P()),
+                         check_vma=False)(*st, affected0, lo, hi, bitmap)
 
 
 @partial(jax.jit, static_argnames=("spec", "batch", "method", "engine",
@@ -93,7 +186,7 @@ def closure_probes_bitmap(spec: GraphSpec, bitmap) -> bool:
 def batch_maintain(spec: GraphSpec, st: GraphState,
                    del_a, del_b, del_valid,
                    ins_a, ins_b, ins_valid,
-                   batch: int = 256, method: str = "sorted",
+                   batch: int = CLOSURE_CHUNK, method: str = "sorted",
                    engine: str = "auto",
                    bitmap: jax.Array | None = None, mesh=None):
     """Apply B deletions + B insertions jointly and maintain phi exactly.
@@ -103,14 +196,14 @@ def batch_maintain(spec: GraphSpec, st: GraphState,
     netting in ``DynamicGraph.apply_batch`` guarantees this).  ``bitmap``,
     when given (bitmap method), must be the adjacency bitmap of the
     POST-update active set (``DynamicGraph`` maintains it incrementally);
-    when it is full-width on every device (``partition="replicated"``) the
-    closure probes it for partner edges instead of binary-searching the
-    sorted rows (``closure_probes_bitmap``) — same affected set, fewer
-    per-element gathers.
+    the closure then probes it for partner edges instead of
+    binary-searching the sorted rows (``closure_probes_bitmap``) — same
+    affected set, fewer per-element gathers — replicated or word-sharded.
     ``mesh`` (static, hashable) runs the frozen-boundary re-peel
-    edge-sharded over ``mesh[spec.shard_axis]`` — the structural pass and
-    affected-set closure are O(B·D) one-shot work and stay replicated; the
-    wave loop is where the devices go.
+    edge-sharded over ``mesh[spec.shard_axis]`` — the structural pass is
+    O(B·D) one-shot work left to the partitioner; the wave loop is where
+    the devices go.  Under ``partition="nodes"`` the closure runs under
+    ``shard_map`` against the word slabs (``_sharded_closure``).
 
     Returns ``(state, lo, hi, stats)`` — the post-update state, the
     widened union affected range (int32 scalars; ``lo > hi`` means nothing
@@ -213,13 +306,7 @@ def batch_maintain(spec: GraphSpec, st: GraphState,
         # insert-only propagation still needs a seed; delete-only the same —
         # an empty union range (lo > hi) admits no seeds and no expansion.
 
-        act_pad = jnp.concatenate([st1.active, jnp.zeros((1,), bool)])
-        phi_pad = jnp.concatenate([st1.phi, jnp.zeros((1,), jnp.int32)])
-
-        def admissible(ids, msk):
-            idc = jnp.minimum(ids, e_cap)
-            p = phi_pad[idc]
-            return msk & (ids < e_cap) & act_pad[idc] & (p >= lo) & (p <= hi)
+        admissible = _admissible(st1.active, st1.phi, lo, hi, e_cap)
 
         # ---- shared frontier seeds ---------------------------------------
         seeds = jnp.zeros((e_cap,), bool)
@@ -233,35 +320,31 @@ def batch_maintain(spec: GraphSpec, st: GraphState,
     with jax.named_scope("closure"):
         # ---- BFS closure over triangle adjacency -------------------------
         probe = closure_probes_bitmap(spec, bitmap)
-
-        def exp_cond(c: _ExpandCarry):
-            return jnp.any(c.frontier) & (c.it < e_cap)
-
-        def exp_body(c: _ExpandCarry):
-            idx = jnp.nonzero(c.frontier, size=batch, fill_value=e_cap)[0]
-            live = idx < e_cap
-            if probe:
-                sides = chunk_partner_edges_bitmap(spec, st1, idx, bitmap)
-            else:
+        if probe and spec.partition == "nodes" and mesh is not None:
+            affected, iters = _sharded_closure(spec, st1, affected0, lo, hi,
+                                               bitmap, batch=batch,
+                                               mesh=mesh)
+        else:
+            def partners(idx):
+                if probe:
+                    return chunk_partner_edges_bitmap(spec, st1, idx, bitmap)
                 p1, p2, tval = chunk_partners(spec, st1, idx, st1.active)
-                sides = ((p1, tval), (p2, tval))
-            nxt = jnp.zeros((e_cap,), bool)
-            for ids, msk in sides:
-                nxt = scatter_or(nxt, ids, admissible(ids, msk))
-            nxt = nxt & ~c.affected
-            processed = scatter_or(jnp.zeros((e_cap,), bool), idx, live)
-            return _ExpandCarry(c.affected | nxt,
-                                (c.frontier & ~processed) | nxt, c.it + 1)
+                return ((p1, tval), (p2, tval))
 
-        out = jax.lax.while_loop(
-            exp_cond, exp_body,
-            _ExpandCarry(affected0, affected0, jnp.int32(0)))
-        affected = out.affected
+            affected, iters = _close(affected0, admissible, partners, e_cap,
+                                     batch)
 
     with jax.named_scope("repeel"):
         # ---- frozen-boundary re-peel (shared engine, peel.py) ------------
         phi_final, stats = run_peel(spec, st1, affected, bitmap=bitmap,
                                     method=method, engine=engine, mesh=mesh)
-    stats = stats._replace(closure_iters=out.it,
+    stats = stats._replace(closure_iters=iters,
                            closure_unfiltered=unfiltered)
-    return st1._replace(phi=phi_final), lo, hi, stats
+    out = st1._replace(phi=phi_final)
+    if mesh is not None:
+        # hand the state back in the placement it came in
+        # (``graph.shard_state``), so the next generation runs this same
+        # program instead of compiling one for the partitioner's layout
+        out = GraphState(*map(jax.lax.with_sharding_constraint, out,
+                              state_sharding(spec, mesh)))
+    return out, lo, hi, stats

@@ -23,6 +23,7 @@ run no peel and stay single-device.
 from __future__ import annotations
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 
 from ..obs import metrics as obs_metrics, trace as obs_trace
@@ -70,20 +71,30 @@ class DynamicGraph:
             # every peel this wrapper launches then shards transparently
             self.spec = with_mesh(self.spec, mesh, shard_axis,
                                   partition=partition)
-        self.state = from_edge_list(self.spec, edges) if len(edges) else None
-        if self.state is None:
-            from .graph import empty_state
-            self.state = empty_state(self.spec)
+        # set-up spans end when their device work has landed, so the
+        # start-up time is named by what it was spent on
+        with obs_trace.span("graph.load", edges=len(edges)):
+            self.state = (from_edge_list(self.spec, edges) if len(edges)
+                          else None)
+            if self.state is None:
+                from .graph import empty_state
+                self.state = empty_state(self.spec)
+            jax.block_until_ready(self.state)
         if mesh is not None:
             # place the edge arrays on their shard row blocks up front so
             # the sharded peels skip the entry reshard
-            self.state = shard_state(self.spec, self.state, mesh)
+            with obs_trace.span("graph.place", shards=self.spec.n_shards):
+                self.state = jax.block_until_ready(
+                    shard_state(self.spec, self.state, mesh))
         self.support_method = support_method
         self._bitmap = None
         self._set_memory_gauges()
-        phi, stats = decomposition.decompose_with_stats(
-            self.spec, self.state, support_method, bitmap=self._bitmap_cache(),
-            mesh=self.mesh)
+        bitmap = self._bitmap_cache()
+        with obs_trace.span("graph.decompose", method=support_method):
+            phi, stats = jax.block_until_ready(
+                decomposition.decompose_with_stats(
+                    self.spec, self.state, support_method, bitmap=bitmap,
+                    mesh=self.mesh))
         self.state = self.state._replace(phi=phi)
         # every maintenance path records a PeelStats — never None (the
         # initial decomposition's peel counts as the first one) — and
@@ -151,13 +162,26 @@ class DynamicGraph:
         if self.support_method != "bitmap":
             return None
         if self._bitmap is None:
-            if self._partitioned():
-                self._bitmap = build_bitmap_partitioned(
-                    self.spec, self.state, self.state.active, self.mesh)
-            else:
-                self._bitmap = build_bitmap(self.spec, self.state,
-                                            self.state.active)
+            with obs_trace.span("graph.bitmap_build",
+                                partition=self.spec.partition):
+                if self._partitioned():
+                    bm = build_bitmap_partitioned(
+                        self.spec, self.state, self.state.active, self.mesh)
+                else:
+                    bm = build_bitmap(self.spec, self.state,
+                                      self.state.active)
+                self._bitmap = jax.block_until_ready(bm)
         return self._bitmap
+
+    def exchange_bytes(self, stats: dict) -> int:
+        """Bytes the node-partitioned bitmap layout put between chips for
+        the peel that reported ``stats`` (``peel.stats_dict``): its waves'
+        psums and its closure's row gathers (``batch.exchange_bytes``); 0
+        on any other layout, which exchanges no bitmap work."""
+        if self.support_method != "bitmap" or not self._partitioned():
+            return 0
+        return batch.exchange_bytes(self.spec, stats["waves"],
+                                    stats["closure_iters"])
 
     def _bitmap_apply(self, dels, inss):
         """Fold structural edge changes into the cached bitmap (O(batch)

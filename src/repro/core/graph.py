@@ -231,23 +231,24 @@ def pad_state(old_spec: GraphSpec, st: GraphState, spec: GraphSpec) -> GraphStat
         nbr=st.nbr, eid=eid, deg=st.deg)
 
 
-def shard_state(spec: GraphSpec, st: GraphState, mesh) -> GraphState:
-    """Place ``st`` for the mesh: edge-axis arrays sharded into their row
-    blocks along ``spec.shard_axis``, node-indexed arrays replicated.  The
-    placement is an optimization (shard_map reshards on entry regardless);
-    values are unchanged."""
+def state_sharding(spec: GraphSpec, mesh) -> GraphState:
+    """The ``NamedSharding`` of each ``GraphState`` field on the mesh:
+    edge-axis arrays in their row blocks along ``spec.shard_axis``,
+    node-indexed arrays replicated."""
     from jax.sharding import NamedSharding, PartitionSpec as P  # lazy: host paths
     ax = spec.shard_axis
     row2 = NamedSharding(mesh, P(ax, None))
     row1 = NamedSharding(mesh, P(ax))
     rep = NamedSharding(mesh, P())
-    return GraphState(
-        edges=jax.device_put(st.edges, row2),
-        active=jax.device_put(st.active, row1),
-        phi=jax.device_put(st.phi, row1),
-        nbr=jax.device_put(st.nbr, rep),
-        eid=jax.device_put(st.eid, rep),
-        deg=jax.device_put(st.deg, rep))
+    return GraphState(edges=row2, active=row1, phi=row1, nbr=rep, eid=rep,
+                      deg=rep)
+
+
+def shard_state(spec: GraphSpec, st: GraphState, mesh) -> GraphState:
+    """Place ``st`` for the mesh (``state_sharding``).  The placement is an
+    optimization (shard_map reshards on entry regardless); values are
+    unchanged."""
+    return GraphState(*map(jax.device_put, st, state_sharding(spec, mesh)))
 
 
 # ---------------------------------------------------------------------------
@@ -638,12 +639,15 @@ def bitmap_sharding(spec: GraphSpec, mesh):
     return NamedSharding(mesh, P())
 
 
+@partial(jax.jit, static_argnames=("spec", "mesh"))
 def build_bitmap_partitioned(spec: GraphSpec, st: GraphState,
                              alive: jax.Array, mesh) -> jax.Array:
     """Word-sharded ``uint32[N, W]`` adjacency bitmap of the alive subgraph:
     every device scatters the full edge table (replicated in) into its own
     slab and drops out-of-slab bits — value-equal to ``build_bitmap``, laid
-    out ``P(None, shard_axis)`` with O(N·W/S) resident per device."""
+    out ``P(None, shard_axis)`` with O(N·W/S) resident per device.  Jitted,
+    like ``update_bitmap_partitioned``: a ``shard_map`` called eagerly runs
+    its body op by op and compiles each op again on every call."""
     from jax.sharding import PartitionSpec as P
 
     ax, wb = spec.shard_axis, spec.word_block
@@ -658,6 +662,7 @@ def build_bitmap_partitioned(spec: GraphSpec, st: GraphState,
                          check_vma=False)(st.edges, alive)
 
 
+@partial(jax.jit, static_argnames=("spec", "set_bits", "mesh"))
 def update_bitmap_partitioned(spec: GraphSpec, bm: jax.Array, u: jax.Array,
                               v: jax.Array, valid: jax.Array, *,
                               set_bits: bool, mesh) -> jax.Array:

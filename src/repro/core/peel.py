@@ -130,8 +130,9 @@ def chunk_partners(spec: GraphSpec, st: GraphState, idx: jax.Array,
     ``alive`` — i.e. ``tval`` marks exactly the triangles of the chunk edges
     that exist in the ``alive`` subgraph.  This is the one wave primitive
     behind the delta-peel engine and Algorithm 1/2 localSupport frontiers;
-    the batch engine's affected-set closure uses it only when it has no
-    replicated adjacency bitmap (``chunk_partner_edges_bitmap`` otherwise).
+    the batch engine's affected-set closure uses it only for the ``sorted``
+    method, which keeps no adjacency bitmap (``chunk_partner_edges_bitmap``
+    otherwise).
     """
     live = idx < spec.e_cap
     idxc = jnp.minimum(idx, spec.e_cap - 1)
@@ -144,7 +145,8 @@ def chunk_partners(spec: GraphSpec, st: GraphState, idx: jax.Array,
 
 
 def chunk_partner_edges_bitmap(spec: GraphSpec, st: GraphState,
-                               idx: jax.Array, bitmap: jax.Array):
+                               idx: jax.Array, bitmap: jax.Array,
+                               axis: str | None = None):
     """Partner edges of a compacted chunk of edge slots, by bitmap probe.
 
     ``idx`` is a fixed-size batch of edge slots (sentinel ``e_cap`` on dead
@@ -158,12 +160,24 @@ def chunk_partner_edges_bitmap(spec: GraphSpec, st: GraphState,
     side and on v's side, unpaired — slot (i, j) of one side and of the
     other need not close the same triangle, so this answers "which edges
     share a triangle with the chunk", not "which triangles".
+
+    Both bitmap layouts: called inside a ``shard_map`` over ``axis`` with
+    ``bitmap`` this device's word slab (``partition="nodes"``), it
+    all-gathers the chunk's endpoint rows from every slab — in device
+    order they are the full-width rows — and probes them unchanged, so it
+    gives exactly the bits the replicated probe gives, for ``2 × C × W``
+    words on the interconnect per call and never the whole bitmap.
     """
     n = spec.n_nodes
     live = idx < spec.e_cap
     idxc = jnp.minimum(idx, spec.e_cap - 1)
     u = jnp.minimum(st.edges[idxc, 0], n - 1)
     v = jnp.minimum(st.edges[idxc, 1], n - 1)
+
+    def full_rows(a):
+        if axis is None:
+            return bitmap[a]
+        return jax.lax.all_gather(bitmap[a], axis, axis=1, tiled=True)
 
     def side(a, rows):
         w = st.nbr[a]                                   # [C, D]
@@ -172,7 +186,7 @@ def chunk_partner_edges_bitmap(spec: GraphSpec, st: GraphState,
         bit = (words >> (wc & 31).astype(jnp.uint32)) & jnp.uint32(1)
         return st.eid[a], live[:, None] & (w < n) & (bit == 1)
 
-    return side(u, bitmap[v]), side(v, bitmap[u])
+    return side(u, full_rows(v)), side(v, full_rows(u))
 
 
 # ---------------------------------------------------------------------------

@@ -132,6 +132,10 @@ _CLOSURE_UNFILTERED_N = obs_metrics.counter(
 _CLOSURE_PROBE_N = obs_metrics.counter(
     "truss_closure_bitmap_probe_total",
     "generations whose closure tested partners against the adjacency bitmap")
+_EXCHANGE_BYTES = obs_metrics.counter(
+    "truss_exchange_bytes_total",
+    "bytes the node-partitioned bitmap layout put between chips (peel-wave "
+    "psums and closure row gathers, from the shapes); 0 when replicated")
 _Q_DEPTH = obs_metrics.gauge(
     "truss_pipeline_queue_depth",
     "acked-but-unapplied records queued (pipeline mode)")
@@ -351,6 +355,7 @@ class TrussService:
             _CLOSURE_ITERS_N.inc(d["closure_iters"])
             _CLOSURE_UNFILTERED_N.inc(d["closure_unfiltered"])
             _CLOSURE_PROBE_N.inc(int(self.graph.last_closure_probed))
+            _EXCHANGE_BYTES.inc(self.graph.exchange_bytes(d))
             self._stats_seen = ps
         return d
 
@@ -1414,6 +1419,7 @@ class TrussService:
             "peel_waves": reg.value("truss_peel_waves_total"),
             "closure_bitmap_probes":
                 reg.value("truss_closure_bitmap_probe_total"),
+            "exchange_bytes": reg.value("truss_exchange_bytes_total"),
             "sheds": reg.value("truss_pipeline_shed_total"),
             "progressive_updates":
                 reg.value("truss_progressive_updates_total"),

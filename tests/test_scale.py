@@ -343,12 +343,15 @@ print("ok")
 """, devices=devices)
 
 
-def test_partitioned_closure_searches_and_matches_replicated():
+@pytest.mark.parametrize("devices", [2, 4])
+def test_partitioned_closure_searches_and_matches_replicated(devices):
     """Under ``partition="nodes"`` each device holds one word slab of the
-    bitmap, so the fused closure keeps the sorted-row search; the
-    replicated graph probes its full bitmap.  Both reach the same phi and
-    every ``PeelStats`` field, the closure's included."""
-    run_py("""
+    bitmap, and the fused closure still probes it: it gathers the chunk's
+    endpoint rows from the slabs, where it once binary-searched the sorted
+    rows.  The replicated graph probes its full bitmap.  Both reach the
+    oracle's phi and the same value in every ``PeelStats`` field, the
+    closure's included."""
+    run_py(f"""
 from repro.core import DynamicGraph, oracle
 from repro.core.batch import closure_probes_bitmap
 from repro.launch.mesh import make_shard_mesh
@@ -362,22 +365,106 @@ batches = [[(0, *e) for e in BASE[:6]] + [(1, *e) for e in absent[:6]],
            [(1, *e) for e in absent[6:14]],
            [(0, *e) for e in BASE[6:14]]]
 g1 = DynamicGraph(N, BASE, support_method="bitmap")
-g2 = DynamicGraph(N, BASE, support_method="bitmap", mesh=make_shard_mesh(2),
-                  partition="nodes")
+g2 = DynamicGraph(N, BASE, support_method="bitmap",
+                  mesh=make_shard_mesh({devices}), partition="nodes")
+assert g2.spec.word_block * {devices} == g2.spec.n_words
 assert closure_probes_bitmap(g1.spec, g1._bitmap_cache())
-assert not closure_probes_bitmap(g2.spec, g2._bitmap_cache())
+assert closure_probes_bitmap(g2.spec, g2._bitmap_cache())
 orc = oracle.Oracle(N, BASE)
 for batch in batches:
     g1.apply_batch(batch, strategy="fused")
     g2.apply_batch(batch, strategy="fused")
     orc.apply(batch)
-    assert g1.last_closure_probed and not g2.last_closure_probed
+    assert g1.last_closure_probed and g2.last_closure_probed
     assert g1.phi_dict() == g2.phi_dict() == orc.phi
     s1, s2 = g1.last_peel_stats, g2.last_peel_stats
     assert [int(x) for x in s1] == [int(x) for x in s2], (s1, s2)
     assert int(s1.closure_iters) >= 1
 print("ok")
-""", devices=2)
+""", devices=devices)
+
+
+def test_partitioned_service_counts_probe_and_exchange():
+    """A ``TrussService`` over four devices with the bitmap node-partitioned
+    runs three 50/50 churn generations to the oracle's phi, compiling
+    nothing after the first.  Each generation's closure probes the slabs,
+    and the exchange counter
+    advances by its collectives' payload computed from the shapes: a psum
+    of ``int32[e_cap]`` per wave and two ``uint32[256, W]`` row gathers per
+    closure iteration.  A replicated service probes too and exchanges
+    nothing."""
+    run_py("""
+import numpy as np
+from repro.core import oracle
+from repro.data.synthetic import powerlaw_graph
+from repro.launch.mesh import make_shard_mesh
+from repro.obs import metrics
+from repro.service import TrussService
+
+N = 2000
+edges = [(int(u), int(v)) for u, v in powerlaw_graph(N, 4, seed=5)]
+
+
+def churn(seed):
+    rng = np.random.default_rng(seed)
+    present = set(edges)
+    out = []
+    for _ in range(3):
+        cur = sorted(present)
+        dels = [cur[i] for i in rng.choice(len(cur), 50, replace=False)]
+        ins = set()
+        while len(ins) < 50:
+            a, b = sorted(int(x) for x in rng.choice(N, 2, replace=False))
+            if (a, b) not in present and (a, b) not in dels:
+                ins.add((a, b))
+        present = (present - set(dels)) | ins
+        out.append([(0, a, b) for a, b in dels]
+                   + [(1, a, b) for a, b in sorted(ins)])
+    return out, present
+
+
+BATCHES, FINAL = churn(11)
+PHI = oracle.scratch_phi(N, FINAL)
+
+
+def counters():
+    return (metrics.REGISTRY.value("truss_closure_bitmap_probe_total"),
+            metrics.REGISTRY.value("truss_exchange_bytes_total"))
+
+
+def drive(svc):
+    probes0, bytes0 = counters()
+    expected = 0
+    for gen, batch in enumerate(BATCHES):
+        if gen == 1:
+            compiles = metrics.REGISTRY.value("truss_xla_compiles_total")
+        svc.submit_many(batch)
+        ps = svc.graph.last_peel_stats
+        w = svc.graph.spec.n_words
+        expected += 4 * (int(ps.waves) * svc.graph.spec.e_cap
+                         + int(ps.closure_iters) * 2 * 256 * w)
+    assert svc.gen == 3
+    # the state comes back in the placement it went in: after the first
+    # generation nothing compiles
+    assert metrics.REGISTRY.value("truss_xla_compiles_total") == compiles
+    assert svc.graph.phi_dict() == PHI
+    probes1, bytes1 = counters()
+    assert probes1 - probes0 == 3
+    return bytes1 - bytes0, expected
+
+
+svc = TrussService(N, edges, flush_every=100, support_method="bitmap",
+                   mesh=make_shard_mesh(4), partition="nodes")
+assert svc.graph.spec.n_shards == 4
+exchanged, expected = drive(svc)
+assert exchanged == expected > 0, (exchanged, expected)
+assert svc.stats()["counters"]["exchange_bytes"] >= exchanged
+
+rep = TrussService(N, edges, flush_every=100, support_method="bitmap")
+exchanged, expected = drive(rep)
+assert exchanged == 0 < expected
+print("ok")
+""", devices=4)
 
 
 # ---------------------------------------------------------------------------

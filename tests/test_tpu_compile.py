@@ -12,7 +12,10 @@ that does not fit the chip's 15.75 GB of HBM.  Sizes are real:
   million-edge tier's W = 1,024 and S in {1, 4};
 * the million-edge tier itself (``powerlaw_graph(32768, 32,
   max_degree=1024)``: 1,049,255 edges), replicated on one chip and
-  node-partitioned over the 2x2 mesh.
+  node-partitioned over the 2x2 mesh;
+* the benchmark's ``epinions`` deployment (``powerlaw_graph(75879, 6)``:
+  507,402 edges; E_cap = 1,014,804, D_max = 4,258, W = 2,372 words): one
+  fused batch maintain, node-partitioned over the 2x2 mesh.
 
 The topology is described inside a fixture (never at import), the
 persistent compilation cache is off around the compiles, and the backend
@@ -34,6 +37,11 @@ from repro.kernels.peel_wave import peel_wave_kernel
 HBM_BYTES = 15.75 * 2**30      # what XLA may place on one v5e chip
 ENRON = GraphSpec(n_nodes=36692, d_max=2286, e_cap=416950)
 MILLION = GraphSpec(n_nodes=32768, d_max=1024, e_cap=1049255)
+EPINIONS = GraphSpec(n_nodes=75879, d_max=4258, e_cap=1014804)
+#: per-device temporaries of the epinions batch maintain: a copy of the
+#: replicated node tables (2.59 GB) for the structural edit, the wave's
+#: [8192, W/4] row chunks and the closure's [256, D_max] blocks
+EPINIONS_TEMP_BYTES = 4 * 2**30
 KERNEL_SHAPES = [
     (ENRON.e_cap, ENRON.n_words),   # replicated bitmap rows, enron-like
     (8192, 1024),                   # partitioned gather chunk, S = 1
@@ -169,4 +177,61 @@ def test_partitioned_bitmap_peel_compiles_for_v5e_2x2(topo, one_chip,
                             engine="delta", mesh=mesh), st, bitmap)
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "all-reduce" in text
+    assert _hbm_bytes(mem) < HBM_BYTES, mem   # bytes per device
+
+
+def test_partitioned_batch_maintain_fits_v5e_2x2_at_epinions_scale(
+        topo, one_chip, monkeypatch):
+    """The ``epinions.ingest`` cell's generation: ``batch_maintain`` with the
+    bitmap node-partitioned over the 2x2 mesh.  The closure gathers only
+    the chunk's ``[256, W/4]`` slab rows per iteration and the wave psums
+    ``int32[E_cap]``; no collective moves the bitmap, and each device's
+    temporaries stay under ``EPINIONS_TEMP_BYTES``."""
+    import re
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.core.batch import batch_maintain
+    from repro.core.graph import with_mesh
+
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    mesh = Mesh(np.asarray(topo.devices[:4]), ("shard",))
+    spec = with_mesh(EPINIONS, mesh, partition="nodes")
+    assert (spec.e_cap, spec.n_words, spec.word_block) == (1014804, 2372, 593)
+    rep = NamedSharding(mesh, P())
+    st = _state(spec, NamedSharding(mesh, P("shard")),
+                NamedSharding(mesh, P("shard", None)), rep)
+    bitmap = jax.ShapeDtypeStruct((spec.n_nodes, spec.n_words), jnp.uint32,
+                                  sharding=NamedSharding(mesh,
+                                                         P(None, "shard")))
+    ids = jax.ShapeDtypeStruct((512,), jnp.int32, sharding=rep)
+    valid = jax.ShapeDtypeStruct((512,), jnp.bool_, sharding=rep)
+    compiled, mem = _compile(
+        lambda st, bm, *batch: batch_maintain(spec, st, *batch,
+                                              method="bitmap", bitmap=bm,
+                                              mesh=mesh),
+        st, bitmap, ids, ids, valid, ids, ids, valid)
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+
+    def collectives(kind):
+        """``(result shape, stage)`` of each ``kind`` instruction."""
+        return [(m.group(1), re.search(
+                    r'op_name="[^"]*jit\(batch_maintain\)/(\w+)',
+                    m.group(2)))
+                for m in re.finditer(
+                    r"= (\S+) " + kind + r"\((.*)", text)]
+
+    gathers = [(shape, st and st.group(1))
+               for shape, st in collectives("all-gather")
+               if shape.startswith("u32")]
+    # the closure's row gathers, per iteration; never the bitmap itself
+    assert gathers and all(shape.startswith("u32[4,593,256]")
+                           and stage == "closure"
+                           for shape, stage in gathers), gathers
+    # the wave's psum of partial supports, under repeel
+    assert [st.group(1) for shape, st in collectives("all-reduce")
+            if shape.startswith("s32[1014804]")] == ["repeel"]
+    assert mem.temp_size_in_bytes < EPINIONS_TEMP_BYTES, mem
     assert _hbm_bytes(mem) < HBM_BYTES, mem   # bytes per device
